@@ -28,7 +28,9 @@ test:
 # instead of erroring out of the whole tier; when gcc IS present a
 # broken native build fails the tier rather than silently falling back
 # (a stale .so from an earlier build would otherwise mask the breakage)
-# (reference `make citest` with --bls-type=fastest, Makefile:129-137)
+# (reference `make citest` with --bls-type=fastest, Makefile:129-137).
+# A host-CPU tier: JAX_PLATFORMS=cpu unless the caller chose otherwise.
+citest: export JAX_PLATFORMS ?= cpu
 citest:
 	@if command -v gcc >/dev/null 2>&1; then $(MAKE) native; \
 	else echo "citest: gcc not found — skipping native build," \
@@ -55,8 +57,7 @@ citest:
 # flake8+mypy; neither ships in this image).  Exits 0 modulo the
 # checked-in ratchet file speclint_baseline.json.  Warm reruns serve
 # findings from the content-hash incremental store
-# (.speclint_cache.json, gitignored; BENCHMARKS round 12 times
-# cold vs warm).  The compiled ladder is generated (gitignored):
+# (.speclint_cache.json, gitignored).  The compiled ladder is generated (gitignored):
 # build it if absent so fresh clones lint out of the box, but never
 # overwrite an existing tree (a drifted or hand-edited one must stay
 # visible to the L3xx pass).
@@ -212,7 +213,7 @@ bench-das-smoke:
 # spec loop, and show near-linear (>= 6x at 8 shards) per-shard kernel
 # scaling on 1M-validator columns; nonzero exit on any regression
 bench-mesh-smoke:
-	XLA_FLAGS="--xla_force_host_platform_device_count=8" \
+	JAX_PLATFORMS=cpu XLA_FLAGS="--xla_force_host_platform_device_count=8" \
 		$(PYTHON) benchmarks/bench_mesh.py
 
 # block-serving pipeline smoke (docs/serving.md): the pipelined lane
@@ -263,13 +264,14 @@ bench-supervisor-smoke:
 obs-report:
 	$(PYTHON) -m consensus_specs_tpu.tools.obs_report
 
+# the sharded verification step on an 8-device virtual host-CPU mesh
 dryrun:
-	$(PYTHON) -c "import __graft_entry__ as g; g.dryrun_multichip(8)"
+	JAX_PLATFORMS=cpu XLA_FLAGS="--xla_force_host_platform_device_count=8" \
+		$(PYTHON) -c "import __graft_entry__ as g; g.dryrun_multichip(8)"
 
-# prewarm the persistent XLA compile cache (fingerprint-keyed) with every
-# program bench.py and the multichip dryrun dispatch - run after checkout
-# or dependency changes so the driver-facing entry points replay cached
-# executables instead of paying cold XLA:CPU compiles
+# prewarm the persistent XLA compile cache (.jax_cache/, or
+# $JAX_COMPILATION_CACHE_DIR) with the programs bench.py, the RLC flush
+# and the graft entry dispatch, on whatever platform JAX reports
 warm:
 	$(PYTHON) -m consensus_specs_tpu.tools.warm
 

@@ -23,7 +23,7 @@ Counter-asserted contracts (nonzero exit on any violation):
    below the unfolded run of the same cases; expected-invalid cases
    show up in ``gen.case_replays`` (optimism never ships — they rerun
    on the plain path);
-4. **wall-clock** (``--full`` only, the BENCHMARKS Round 17 shape) —
+4. **wall-clock** (``--full`` only: 19 generators x 5 forks, minimal preset) —
    factory ≥ 3× over the serial baseline on the full multi-fork
    minimal-preset subset when the host has ≥ 4 cores (the pool
    parallelizes case compute AND amortizes the 19 startups).  On
@@ -102,8 +102,9 @@ def run_factory(out: str, generators, forks, presets, workers) -> float:
 def census_leg(generators, forks, presets, workdir):
     """In-process fold-vs-plain run of the same cases: the counter
     evidence for the sign-memo and one-pairing-per-case claims."""
-    from consensus_specs_tpu.utils.jax_env import force_cpu_platform
-    force_cpu_platform()
+    # host tool: its fork pool's workers must not contend for a chip
+    import jax
+    jax.config.update("jax_platforms", "cpu")
     from consensus_specs_tpu.gen import corpus as corpus_mod
     from consensus_specs_tpu.gen import gen_runner
     from consensus_specs_tpu.test_infra import context as ctx
@@ -134,7 +135,7 @@ def main() -> int:
     parser.add_argument("--full", action="store_true",
                         help="all generators, all forks, minimal preset; "
                              "gates the >= 3x wall-clock claim "
-                             "(BENCHMARKS Round 17)")
+                             "(19 generators x 5 forks, minimal)")
     parser.add_argument("-j", "--workers", type=int,
                         default=min(8, os.cpu_count() or 1))
     args = parser.parse_args()

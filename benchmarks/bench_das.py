@@ -14,7 +14,7 @@ Three asserted claims back the DAS engine's shipping default (on):
    ``das.recover_many`` (shared vanishing polynomial + batch inversion
    across blobs missing the same columns) must beat the per-blob
    spec-markdown loop, byte-identically.  The measured ratio is
-   recorded in BENCHMARKS.md.
+   printed in the result line.
 
 3. **Disabled overhead** — with ``CS_TPU_DAS=0`` the dispatch wrapper
    must add under 2% to the spec loop it falls through to (exact

@@ -1,8 +1,10 @@
 """Mesh-sharded SPMD engine benchmark (``make bench-mesh-smoke``,
-CI-wired).  Runs on an 8-way virtual host-device mesh (forced below,
-before jax imports) so the census exercises REAL SPMD partitioning —
-shard_map programs, NamedSharding placements, psum collectives —
-without TPU hardware.
+CI-wired).  Runs on whatever devices JAX reports; with
+``JAX_PLATFORMS=cpu`` (``make bench-mesh-smoke``) that is an 8-way
+virtual host-device mesh (device count forced below, before jax
+imports) so the census exercises REAL SPMD partitioning — shard_map
+programs, NamedSharding placements, psum collectives — without TPU
+hardware.
 
 Four counter-asserted contracts:
 
@@ -35,8 +37,8 @@ import time
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 # the mesh needs addressable devices BEFORE the first jax import; on a
-# TPU host the real topology wins, on CPU hosts we force the 8-way
-# virtual mesh the CI legs and the multichip dryrun use
+# TPU host the real topology wins, on the CPU platform this is the
+# 8-way virtual mesh the CI legs use
 _flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in _flags:
     os.environ["XLA_FLAGS"] = \
@@ -149,11 +151,8 @@ def main():
     ap.add_argument("--min-scaling", type=float, default=6.0)
     args = ap.parse_args()
 
-    from consensus_specs_tpu.utils.jax_env import (
-        setup_compile_cache, force_cpu_platform)
+    from consensus_specs_tpu.utils.jax_env import setup_compile_cache
     setup_compile_cache()
-    if not os.environ.get("CS_TPU_BENCH_REAL_DEVICES"):
-        force_cpu_platform()
 
     import numpy as np
     from consensus_specs_tpu.forks import build_spec

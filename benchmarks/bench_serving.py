@@ -27,7 +27,7 @@ Counter-asserted contracts (nonzero exit on any violation):
 5. **throughput** — sustained slots/sec (best-of-reps, aggregated over
    the stream mix) is strictly higher pipelined than sync;
 6. **chunk-level snapshot cost** — on a large registry (mainnet preset,
-   1M validators in the BENCHMARKS configuration), ``clone_state``
+   1M validators in the full shape), ``clone_state``
    beats ``state.copy()`` while staying root-identical, including after
    divergent mutation of both snapshots.
 
@@ -35,8 +35,8 @@ p50/p99 block-ingest latency comes from the ``serving.ingest_latency``
 histogram; the pipelined lane trades per-block latency (blocks wait for
 their window barrier) for throughput, so latency is reported, not
 bounded.  ``--smoke`` is the CI shape; the full shape
-(``--clone-validators 1048576`` with ``make warm`` caches) is the
-BENCHMARKS.md configuration.
+(``--clone-validators 1048576`` with ``make warm`` caches) is BASELINE.md
+config #5's registry.
 """
 import argparse
 import json

@@ -18,8 +18,8 @@ via the ``state_arrays.*`` telemetry counters:
    copy-on-write census strictly below columns x replays.
 
 Exits nonzero on any violation.  ``--smoke`` runs the small CI shape;
-the full shape (``--validators 1048576 --slots 32``) is the
-BENCHMARKS.md configuration.
+the full shape (``--validators 1048576 --slots 32``) is BASELINE.md
+config #5.
 """
 import argparse
 import json

@@ -236,8 +236,9 @@ def main(args=None) -> int:
                         help="skip parent cache pre-warming")
     ns = parser.parse_args(args)
 
-    from consensus_specs_tpu.utils.jax_env import force_cpu_platform
-    force_cpu_platform()
+    # host tool: its fork pool's workers must not contend for a chip
+    import jax
+    jax.config.update("jax_platforms", "cpu")
     from consensus_specs_tpu.test_infra import context as ctx
     ctx.DEFAULT_BLS_ACTIVE = True
 

@@ -249,8 +249,9 @@ def main(args=None) -> int:
     parser.add_argument("-v", "--verbose", action="store_true")
     ns = parser.parse_args(args)
 
-    from consensus_specs_tpu.utils.jax_env import force_cpu_platform
-    force_cpu_platform()
+    # host tool: its fork pool's workers must not contend for a chip
+    import jax
+    jax.config.update("jax_platforms", "cpu")
 
     summary = replay_tree(ns.output_dir, verbose=ns.verbose)
     print(f"corpus-check: replayed={summary['replayed']} "

@@ -5,7 +5,7 @@ The per-validator epoch loops (``process_rewards_and_penalties``,
 ``process_inactivity_updates``, ``process_effective_balance_updates``,
 ``process_registry_updates`` eligibility scans, ``process_slashings``)
 are O(validators) python iterations over SSZ typed views — the last
-python-loop-bound hot path at registry scale (BENCHMARKS.md config #5:
+python-loop-bound hot path at registry scale (BASELINE.md config #5:
 the 1M-validator epoch transition is all epoch-loop time).  This module
 re-expresses them as columnar array kernels over the canonical
 struct-of-arrays state store (``state/arrays.py``): columns are

@@ -122,6 +122,19 @@ def _ek():
 _PROGRAMS = {}
 
 
+def _psum_u64(x):
+    """``psum`` of uint64 partials, reduced as int64: the TPU lowers a
+    sum all-reduce over s64 but not over u64 (v5e AOT compile, PR 21),
+    and two's-complement addition mod 2**64 is bit-identical to
+    unsigned.  Still ONE psum equation (the PSUM_BUDGET census)."""
+    import jax
+    import jax.numpy as jnp
+    lax = jax.lax
+    return lax.bitcast_convert_type(
+        lax.psum(lax.bitcast_convert_type(x, jnp.int64), mesh_state.AXIS),
+        jnp.uint64)
+
+
 def _program(kind, mesh, static, builder):
     key = (kind, mesh, static)
     prog = _PROGRAMS.get(key)
@@ -154,7 +167,7 @@ def _p_altair_sums(mesh, n_flags):
     def build():
         import jax
         import jax.numpy as jnp
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         def local(eff, act, ext, sl, part, scal):
             prev, cur = scal[0], scal[1]
@@ -167,7 +180,7 @@ def _p_altair_sums(mesh, n_flags):
                     jnp, act, ext, sl, part, prev, f)
                 parts.append(jnp.sum(jnp.where(participating, eff, zero),
                                      dtype=jnp.uint64))
-            return jax.lax.psum(jnp.stack(parts), mesh_state.AXIS)
+            return _psum_u64(jnp.stack(parts))
 
         in_specs, _ = _shard_specs(mesh, 5, 1)
         from jax.sharding import PartitionSpec as P
@@ -185,14 +198,14 @@ def _p_masked_sums(mesh):
     def build():
         import jax
         import jax.numpy as jnp
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         def local(eff, masks):
             parts = jnp.sum(
                 jnp.where(masks, eff[None, :], jnp.uint64(0)),
                 axis=1, dtype=jnp.uint64)
-            return jax.lax.psum(parts, mesh_state.AXIS)
+            return _psum_u64(parts)
 
         axis = mesh_state.AXIS
         return jax.jit(shard_map(
@@ -210,7 +223,7 @@ def _p_active_sums(mesh, k):
     def build():
         import jax
         import jax.numpy as jnp
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         def local(eff, act, ext, *rest):
@@ -226,7 +239,7 @@ def _p_active_sums(mesh, k):
                     parts.append(jnp.sum(
                         jnp.where(masks[i], eff, zero),
                         dtype=jnp.uint64))
-            return jax.lax.psum(jnp.stack(parts), mesh_state.AXIS)
+            return _psum_u64(jnp.stack(parts))
 
         axis = mesh_state.AXIS
         in_specs = (P(axis), P(axis), P(axis)) \
@@ -246,7 +259,7 @@ def _p_shard_stats(mesh, k):
     def build():
         import jax
         import jax.numpy as jnp
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         def local(*cols):
@@ -287,7 +300,7 @@ def _p_incl_scan(mesh):
     def build():
         import jax
         import jax.numpy as jnp
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         def local(anchor, idx, keys):
@@ -318,7 +331,7 @@ def _p_altair_deltas(mesh, static):
     def build():
         import jax
         import jax.numpy as jnp
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         ek = _ek()
 
         # speclint: guarded-by-caller (_altair_rewards bounds
@@ -370,7 +383,7 @@ def _p_phase0_deltas(mesh, static):
     def build():
         import jax
         import jax.numpy as jnp
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         ek = _ek()
 
         # speclint: guarded-by-caller (_phase0_rewards bounds
@@ -423,7 +436,7 @@ def _p_inactivity(mesh, static):
     def build():
         import jax
         import jax.numpy as jnp
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         ek = _ek()
 
         def local(act, ext, sl, wd, part, scores, scal):
@@ -450,7 +463,7 @@ def _p_slashings(mesh, static):
     def build():
         import jax
         import jax.numpy as jnp
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         ek = _ek()
 
         def local(eff, sl, wd, balances, scal):
@@ -477,7 +490,7 @@ def _p_eff_balance(mesh, static):
 
     def build():
         import jax
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         ek = _ek()
 
         def local(balances, eff):
@@ -518,7 +531,7 @@ def _p_registry_scan(mesh, static):
     def build():
         import jax
         import jax.numpy as jnp
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         def local(aee, act, ext, eff, scal):

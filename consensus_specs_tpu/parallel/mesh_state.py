@@ -42,7 +42,7 @@ chunks) keep the engine out of small registries, where host numpy wins;
 device-count gate.
 
 uint64 columns need 64-bit lanes: every placement and program dispatch
-runs inside ``jax.experimental.enable_x64`` so the rest of the process
+runs inside ``jax.enable_x64`` so the rest of the process
 (the u32-limb BLS/SHA kernels) keeps the default dtype rules.
 
 Device-loss recovery (docs/recovery.md): a device dropping out of the
@@ -119,9 +119,8 @@ def device_count() -> int:
     never imported jax answers 0 WITHOUT importing it: the mesh gate
     sits on every epoch dispatch and every full tree build, and a
     pure-host replay (spec loops, numpy engines, benches with BLS off)
-    must not pay a jax backend initialization — or risk an
-    accelerator-plugin probe — just to learn there is nothing to shard
-    over."""
+    must not pay a jax backend initialization just to learn there is
+    nothing to shard over."""
     global _DEVICE_COUNT
     if _DEVICE_COUNT is None:
         import sys
@@ -276,8 +275,8 @@ def pad_amount(n: int, shards: int = None) -> int:
 def x64():
     """The scoped 64-bit-lane context every mesh placement/dispatch runs
     under (module docstring)."""
-    import jax.experimental
-    return jax.experimental.enable_x64()
+    import jax
+    return jax.enable_x64(True)
 
 
 def place(host: np.ndarray, mesh, pad_value=0):

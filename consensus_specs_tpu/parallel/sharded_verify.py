@@ -18,7 +18,7 @@ shape XLA:CPU's fusion pass cannot compile on the 1-core dryrun host —
 the round-1/round-2 dryrun timeouts.)
 
 ``__graft_entry__.dryrun_multichip`` and ``tests/test_multichip.py``
-exercise this on the 8-device virtual CPU mesh.
+exercise this on the 8-device virtual CPU mesh (or real chips).
 """
 import numpy as np
 import jax
@@ -57,11 +57,11 @@ def make_sharded_agg(mesh):
     ordered combine on every device.  Returns ``agg(pk_pts) ->
     total[data_batch]`` (unnormalized projective aggregate).
 
-    Exposed separately so ``__graft_entry__``'s hybrid dryrun fallback
-    runs the EXACT collective program the full step uses.
+    Exposed separately from the full step so tests can check the
+    collective alone.
     """
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from consensus_specs_tpu.ops.jax_bls import points as PT
 
     agg_size = mesh.shape["agg"]
@@ -75,7 +75,7 @@ def make_sharded_agg(mesh):
     pk_spec = P("data", "agg")
     return jax.jit(shard_map(
         local_agg, mesh=mesh, in_specs=((pk_spec,) * 3,),
-        out_specs=P("data"), check_rep=False))
+        out_specs=P("data"), check_vma=False))
 
 
 def make_sharded_agg_verify(mesh):
@@ -117,7 +117,7 @@ def make_sharded_msm(mesh_devices):
     leading axis.  n_points must divide evenly by the mesh size.
     """
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from consensus_specs_tpu.ops.jax_bls import points as PT
     from consensus_specs_tpu.ops.jax_bls import msm as M
     from consensus_specs_tpu.parallel import mesh_state
@@ -137,7 +137,7 @@ def make_sharded_msm(mesh_devices):
         local_msm, mesh=mesh,
         in_specs=(jax.tree_util.tree_map(lambda _: spec, (0, 0, 0)),
                   spec),
-        out_specs=P(), check_rep=False))
+        out_specs=P(), check_vma=False))
 
 
 def make_sharded_g2_msm(mesh_devices):
@@ -157,7 +157,7 @@ def make_sharded_g2_msm(mesh_devices):
     B must divide evenly by the mesh size.
     """
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from consensus_specs_tpu.ops.jax_bls import points as PT
     from consensus_specs_tpu.parallel import mesh_state
 
@@ -174,7 +174,7 @@ def make_sharded_g2_msm(mesh_devices):
         lambda _: spec, ((0, 0), (0, 0), (0, 0)))
     return jax.jit(shard_map(
         local_msm, mesh=mesh, in_specs=(g2_tree_spec, spec),
-        out_specs=P(), check_rep=False))
+        out_specs=P(), check_vma=False))
 
 
 _SHARDED_G2_MSM_CACHE = {}

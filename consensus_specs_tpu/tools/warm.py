@@ -1,14 +1,11 @@
 """Prewarm the persistent XLA compile cache for this machine.
 
-Compiles every staged program that ``bench.py`` and
-``__graft_entry__.dryrun_multichip`` dispatch, so those driver-facing
-entry points replay executables from ``.jax_cache/<key>/`` instead of
-paying the cold XLA:CPU compile (which exceeds any reasonable driver
-budget on a 1-core host - the round-1..3 artifact-timeout root cause).
-The cache directory is keyed by jaxlib/libtpu build AND a CPU-feature
-fingerprint (``utils/jax_env.keyed_cache_dir``), so artifacts are only
-ever replayed on a matching machine; on a new machine this tool simply
-recompiles into a fresh keyed directory.
+Compiles the staged programs that ``bench.py``, the RLC flush,
+``__graft_entry__`` and (with two or more devices) its multichip dryrun
+dispatch, so later processes replay executables from the cache
+(``utils/jax_env.cache_dir``: ``$JAX_COMPILATION_CACHE_DIR`` or
+``.jax_cache/``) instead of paying the cold compile.  Everything runs
+in this one process, on whatever platform JAX reports.
 
 Run ``make warm`` (or ``python -m consensus_specs_tpu.tools.warm``)
 after checkout / dependency changes.  Stages are warmed in increasing
@@ -97,94 +94,27 @@ def warm_entry():
     g = importlib.import_module("__graft_entry__")
     t0 = time.time()
     fn, args = g.entry()
-    out = _np.asarray(jax.jit(fn)(*args))
-    if out.dtype == bool:
-        assert bool(out.all())          # pairing-check path: all valid
-    else:
-        # CPU-fallback ladder computes a^(p-2) over rows 1..64: row 0 is
-        # inv(1) == 1 (Montgomery ONE_M), and no row may be zero
-        from consensus_specs_tpu.ops.jax_bls.limbs import ONE_M
-        assert _np.array_equal(out[0], ONE_M)
-        assert bool((out != 0).any(axis=-1).all())
+    assert bool(_np.asarray(jax.jit(fn)(*args)).all())
     _log(f"graft entry compile check: {time.time() - t0:.1f}s")
 
 
-def warm_dryrun(n_devices=8):
-    """Warm the compile cache the BUDGETED dryrun replays: the staged
-    collective (8-device topology) and the compiled pairing downstream
-    (single-device keys), each in a child with the hermetic-CPU env the
-    dryrun's own children use (``cpu_subprocess_env``: no accelerator
-    plugin, no remote compile) — artifacts land in the hermetic cache
-    directory with this host's own machine features.  Then run
-    ``_dryrun_inner`` once with no budget so the one-process full path
-    gets a genuine completed measurement (able to re-qualify or
-    disqualify phase 1 via the marker)."""
-    import subprocess
-    import tempfile
-    from consensus_specs_tpu.utils.jax_env import cpu_subprocess_env
-    here = os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    npz = tempfile.NamedTemporaryFile(suffix=".npz", delete=False).name
+def warm_dryrun():
+    """Compile the sharded verification step over every device JAX
+    reports (skipped on a single device: there is no mesh to warm)."""
+    import importlib
+    import jax
+    n = len(jax.devices())
+    if n < 2:
+        _log("dryrun skipped: one device")
+        return
     t0 = time.time()
-    import re as _re
-
-    def _strip_count(env):
-        env["XLA_FLAGS"] = _re.sub(
-            r"--xla_force_host_platform_device_count=\d+", "",
-            env.get("XLA_FLAGS", "")).strip()
-        return env
-
-    # mesh children get EXACTLY n_devices (an ambient flag with another
-    # count would make them die or key the cache wrongly); the
-    # downstream child gets NO flag, matching dryrun_multichip's env_ds
-    # so its artifacts land under the same single-device cache keys
-    env_mesh = _strip_count(cpu_subprocess_env())
-    env_mesh["XLA_FLAGS"] = (
-        env_mesh["XLA_FLAGS"]
-        + f" --xla_force_host_platform_device_count={n_devices}").strip()
-    env_single = _strip_count(cpu_subprocess_env())
-    try:
-        proc = subprocess.run(
-            [sys.executable, "-c",
-             f"import __graft_entry__ as g; "
-             f"g._dryrun_collective({n_devices}, {npz!r})"],
-            cwd=here, env=env_mesh)
-        if proc.returncode != 0:
-            raise RuntimeError(f"collective warm failed rc={proc.returncode}")
-        _log(f"dryrun collective warmed: {time.time() - t0:.1f}s")
-        t0 = time.time()
-        proc = subprocess.run(
-            [sys.executable, "-c",
-             f"import __graft_entry__ as g; "
-             f"g._dryrun_compiled_downstream({npz!r})"],
-            cwd=here, env=env_single)
-        if proc.returncode != 0:
-            raise RuntimeError(f"downstream warm failed rc={proc.returncode}")
-        _log(f"dryrun downstream warmed: {time.time() - t0:.1f}s")
-    finally:
-        try:
-            os.unlink(npz)
-        except OSError:
-            pass
-    t0 = time.time()
-    proc = subprocess.run(
-        [sys.executable, "-c",
-         f"import __graft_entry__ as g; g._dryrun_inner({n_devices})"],
-        cwd=here, env=env_mesh)
-    if proc.returncode != 0:
-        raise RuntimeError(f"dryrun inner warm failed rc={proc.returncode}")
-    _log(f"dryrun_multichip({n_devices}) full one-process path: "
-         f"{time.time() - t0:.1f}s (completed measurement recorded)")
+    importlib.import_module("__graft_entry__").dryrun_multichip(n)
+    _log(f"dryrun_multichip({n}): {time.time() - t0:.1f}s")
 
 
 def main():
     import argparse
     parser = argparse.ArgumentParser()
-    parser.add_argument("--platform", choices=("auto", "cpu"),
-                        default="auto",
-                        help="cpu: pin XLA:CPU (the dryrun cache and the "
-                             "bench fallback path); auto: probe the "
-                             "accelerator and use it if it answers")
     parser.add_argument("--stage",
                         choices=("all", "bench", "dryrun", "entry", "rlc",
                                  "fft"),
@@ -192,15 +122,10 @@ def main():
     ns = parser.parse_args()
     sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__)))))
-    from consensus_specs_tpu.utils.jax_env import (
-        setup_compile_cache, ensure_working_backend, force_cpu_platform)
+    from consensus_specs_tpu.utils.jax_env import setup_compile_cache
     cache = setup_compile_cache()
-    _log(f"cache dir: {cache}")
-    if ns.platform == "cpu":
-        force_cpu_platform()
-        _log("platform pinned: cpu")
-    else:
-        _log(f"platform: {ensure_working_backend()}")
+    import jax
+    _log(f"cache dir: {cache}; platform: {jax.default_backend()}")
     if ns.stage in ("all", "bench"):
         warm_bench()
     if ns.stage in ("all", "rlc"):
@@ -209,8 +134,7 @@ def main():
         warm_fft()
     if ns.stage in ("all", "entry"):
         warm_entry()
-    # the dryrun re-execs via subprocess paths of __graft_entry__; warm it
-    # last (it shares most staged programs with the bench pipeline).
+    # last: it shares most staged programs with the bench pipeline
     if ns.stage in ("all", "dryrun"):
         warm_dryrun()
     _log("done")

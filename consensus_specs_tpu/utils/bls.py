@@ -7,7 +7,7 @@ Mirrors the reference's backend-switch design
   use_py()       pure-Python oracle (role of the reference's py_ecc)
   use_jax()      batched JAX kernels, jit-compiled for TPU (replaces the
                  reference's milagro/arkworks Rust backends)
-  use_fastest()  jax if available, else py
+  use_fastest()  jax on an accelerator, else native C, else py
 
 plus the test kill-switch ``bls_active`` with STUB constants
 (``bls.py:49-57,93-104``): when inactive, Sign returns a stub and verifies
@@ -78,24 +78,22 @@ def use_native():
 
 def use_fastest():
     """Backend ladder (reference ``fastest_bls``, bls.py:35-53): the JAX
-    kernels when an accelerator is attached, else the native C library,
-    else the python oracle.  On a bare CPU the jax path pays minutes of
-    XLA compile for sub-oracle throughput, so it is only 'fastest' when
-    a real device is present."""
-    try:
-        from consensus_specs_tpu.utils.jax_env import accelerator_cached
-        if accelerator_cached():
-            use_jax()
-            return
-    except Exception:
-        pass
+    kernels when JAX's default backend is an accelerator, else the
+    native C library, else the python oracle.  On a bare CPU the jax
+    path pays minutes of XLA compile for sub-oracle throughput, so it is
+    only 'fastest' when a real device is present.
+
+    Asks JAX itself (initializing the backend if nothing has yet): the
+    platform is whatever JAX says, and ``JAX_PLATFORMS=cpu`` is how a
+    caller asks for the host."""
+    import jax
+    if jax.default_backend() != "cpu":
+        use_jax()
+        return
     try:
         use_native()
-    except Exception:
-        try:
-            use_jax()
-        except Exception:
-            use_py()
+    except RuntimeError:
+        use_py()
 
 
 def backend_name() -> str:

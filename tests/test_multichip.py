@@ -32,7 +32,7 @@ def test_sharded_g1_aggregate_matches_host():
     """Partial G1 sums per shard + all_gather combine == host aggregation."""
     _require_devices(8)
     from jax.sharding import Mesh, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from consensus_specs_tpu.utils import bls
     from consensus_specs_tpu.ops.jax_bls import points as PT
     from consensus_specs_tpu.ops import bls_jax
@@ -60,7 +60,7 @@ def test_sharded_g1_aggregate_matches_host():
     step = jax.jit(shard_map(
         local, mesh=mesh,
         in_specs=(jax.tree_util.tree_map(lambda _: P("agg"), packed),),
-        out_specs=P(), check_rep=False))
+        out_specs=P(), check_vma=False))
     out = step(packed)
     got = PT.g1_unpack(jax.tree_util.tree_map(lambda a: a[None], out))
     assert got.to_compressed() == expected
@@ -91,7 +91,7 @@ def test_sharded_sum_collective_layout():
     """Sanity: the mesh really has 8 addressable devices and psum runs."""
     _require_devices(8)
     from jax.sharding import Mesh, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     mesh = Mesh(np.array(jax.devices()[:8]), ("d",))
     x = jnp.arange(8.0)

@@ -1252,7 +1252,7 @@ def test_e1203_checkpoint_save_in_scope(tmp_path):
 _FX_SHARD = (
     "import jax\n"
     "import jax.numpy as jnp\n"
-    "from jax.experimental.shard_map import shard_map\n"
+    "from jax import shard_map\n"
     "PSUM_BUDGET = {'demo': 1}\n"
     "def _p_sums(mesh):\n"
     "    def build():\n"
@@ -1308,7 +1308,7 @@ def test_e1214_unbudgeted_sub_and_stale_entry():
 
 def test_e1211_captured_host_state_in_body():
     src = (
-        "from jax.experimental.shard_map import shard_map\n"
+        "from jax import shard_map\n"
         "def _p_bad(mesh, sa):\n"
         "    cols = sa.registry()\n"
         "    def build():\n"
@@ -1323,7 +1323,7 @@ def test_e1211_captured_host_state_in_body():
 
 def test_e1211_static_config_capture_is_clean():
     src = (
-        "from jax.experimental.shard_map import shard_map\n"
+        "from jax import shard_map\n"
         "def _p_ok(mesh, static):\n"
         "    (increment, in_leak) = static\n"
         "    weights = (1, 2, 3)\n"
@@ -1340,7 +1340,7 @@ def test_e1211_static_config_capture_is_clean():
 def test_e1212_host_concretization_in_body():
     src = (
         "import numpy as np\n"
-        "from jax.experimental.shard_map import shard_map\n"
+        "from jax import shard_map\n"
         "def _p_bad(mesh):\n"
         "    def build():\n"
         "        def local(eff):\n"
