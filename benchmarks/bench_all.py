@@ -388,21 +388,20 @@ def main():
                              "comparison (e.g. 16384,262144,1048576)")
     parser.add_argument("--profile", action="store_true",
                         help="print the per-stage span breakdown "
-                             "(utils/profiling) after each config")
+                             "(obs.tracing) after each config")
     ns = parser.parse_args()
     _EPOCH_SHAPES = [int(s) for s in ns.epoch_shapes.split(",")]
     if ns.profile:
-        from consensus_specs_tpu.utils import profiling
-        profiling.enable()
+        from consensus_specs_tpu.obs import tracing
+        tracing.enable()
     for key in ns.configs.split(","):
         if ns.profile:
-            from consensus_specs_tpu.utils import profiling
-            profiling.reset()
+            tracing.reset()
         result = CONFIGS[key.strip()]()
         print(json.dumps(result), flush=True)
         if ns.profile:
             print(json.dumps({"config": key.strip(),
-                              "stages": profiling.stats()}), flush=True)
+                              "stages": tracing.stats()}), flush=True)
 
 
 if __name__ == "__main__":

@@ -1,12 +1,10 @@
 """Hierarchical tracing spans: a per-replay span tree with wall-clock,
 call counts, self-vs-cumulative time, and attached counter deltas.
 
-Replaces the flat span timer that lived in ``utils/profiling.py`` (that
-module is now a thin alias layer over this one).  A span both feeds a
-flat per-name aggregate (``stats()`` — the old surface, now with the
-nesting double-count fixed via explicit self-time) and a position-aware
-tree (``span_tree()``) keyed by call path, so a 32-slot replay reads
-as::
+A span both feeds a flat per-name aggregate (``stats()``, with
+explicit self-time so nested spans do not double-count) and a
+position-aware tree (``span_tree()``) keyed by call path, so a 32-slot
+replay reads as::
 
     state_transition            32   1.84s (self 0.02s)
       process_slots             32   1.21s (self 0.11s)
@@ -25,6 +23,14 @@ module-global read in ``__enter__`` and one attribute test in
 ``__exit__`` — branch-predictable, allocation-free, and measured at
 <2% on the 32-slot replay by ``benchmarks/bench_obs_overhead.py``.
 
+An enabled span is also a profiler TraceMe of the same name
+(``jax.profiler.TraceAnnotation``), so inside a profiler session
+(``jax.profiler.start_trace``) spans land on the calling thread's host
+line of the trace, nested as they ran, on the clock the device events
+are aligned to.  Outside a session a TraceMe costs one atomic check.
+``obs`` never imports jax: the annotation class is resolved once jax has
+been imported by someone else.
+
 Span state is thread-local, so by default concurrent threads build
 disjoint subtrees under the shared root.  Cross-thread causality is
 explicit: the submitting thread calls :func:`capture_context` while
@@ -39,6 +45,7 @@ silently merging it (speclint O504 statically flags thread submits
 that skip the handoff).
 """
 import itertools
+import sys
 import threading
 import time
 
@@ -48,6 +55,17 @@ from . import registry
 
 _enabled = env_flags.PROFILE or env_flags.TRACE
 _trace_counters = env_flags.TRACE
+_annotation = None      # jax.profiler.TraceAnnotation, once jax is loaded
+
+
+def _annotation_cls():
+    """The profiler's ``TraceAnnotation`` once jax is in ``sys.modules``,
+    else None (spans before that carry no profiler event)."""
+    global _annotation
+    if _annotation is None and "jax" in sys.modules:
+        from jax.profiler import TraceAnnotation
+        _annotation = TraceAnnotation
+    return _annotation
 
 
 class _Node:
@@ -205,7 +223,7 @@ class span:
     to construct per call site.
     """
 
-    __slots__ = ("name", "_node", "_t0", "_c0")
+    __slots__ = ("name", "_node", "_t0", "_c0", "_annot")
 
     def __init__(self, name: str):
         self.name = name
@@ -229,6 +247,11 @@ class span:
         if flight._armed:
             flight.record("span>", self.name)
         self._c0 = registry.counter_values() if _trace_counters else None
+        annot = _annotation or _annotation_cls()
+        self._annot = (annot(self.name) if annot and annot.is_enabled()
+                       else None)
+        if self._annot is not None:
+            self._annot.__enter__()
         self._t0 = time.perf_counter()
         return self
 
@@ -238,6 +261,9 @@ class span:
             return False
         dt = time.perf_counter() - self._t0
         self._node = None
+        if self._annot is not None:
+            self._annot.__exit__(exc_type, exc, tb)
+            self._annot = None
         if flight._armed:
             flight.record("span<", node.name, dt)
         stack = _stack()

@@ -28,8 +28,8 @@ from consensus_specs_tpu.ops.jax_bls.backend import (
     xp as jnp, kjit, NUMPY_KERNELS)
 
 from consensus_specs_tpu.ops.bls12_381 import ciphersuite as _oracle
-from consensus_specs_tpu.utils import profiling
-from consensus_specs_tpu.utils.profiling import span
+from consensus_specs_tpu.obs import registry, tracing
+from consensus_specs_tpu.obs.tracing import span
 from consensus_specs_tpu.ops.bls12_381.curve import (
     G1Point, G2Point, G1_GENERATOR, g1_from_compressed, g2_from_compressed)
 from consensus_specs_tpu.ops.jax_bls import points as PT
@@ -42,7 +42,7 @@ def _profile_sync(tree):
     """Drain device work at a stage boundary, but ONLY while profiling —
     unconditional blocking would serialize the async dispatch pipeline
     the staged TPU path relies on."""
-    if profiling.is_enabled():
+    if tracing.is_enabled():
         jax.block_until_ready(tree)
 
 # Cold-path delegation (oracle)
@@ -302,9 +302,11 @@ def verify_aggregates_batch(items) -> list:
 def _verify_aggregates_batch(items) -> list:
     results_host = [None] * len(items)
     rows = []
-    for idx, (pubkeys, msg, sig) in enumerate(items):
-        pts = [_packed_g1(pk) for pk in pubkeys]
-        spt = _decompress_g2(sig)
+    pk_pts = [[_packed_g1(pk) for pk in pubkeys] for pubkeys, _, _ in items]
+    with span("bls.stage.sig_decompress"):
+        sig_pts = [_decompress_g2(sig) for _, _, sig in items]
+    for idx, ((pubkeys, msg, _), pts, spt) in enumerate(
+            zip(items, pk_pts, sig_pts)):
         if len(pubkeys) == 0 or any(p is None for p in pts) or spt is None:
             results_host[idx] = False
             continue
@@ -465,6 +467,11 @@ def _bits_msb(scalars, n_bits: int) -> np.ndarray:
 
 
 RLC_SCALAR_BITS = 128
+# Pubkey rows of the RLC tree sum: ``live`` rows hold a key, ``dispatched``
+# is the (lane bucket x padded width) grid the program adds up
+_PK_ROWS_LIVE = registry.counter("bls.rlc.pk_rows").labels(kind="live")
+_PK_ROWS_DISPATCHED = registry.counter("bls.rlc.pk_rows").labels(
+    kind="dispatched")
 
 
 def rlc_combined_check(pk_rows, msgs, sig_pts, scalars, extra_pairs=(),
@@ -491,6 +498,8 @@ def rlc_combined_check(pk_rows, msgs, sig_pts, scalars, extra_pairs=(),
         npk_pad = max(_N_MIN, _pow2(max(len(r) for r in pk_rows)))
         rows = list(pk_rows) + [[]] * (bucket - n)
         pad_scalars = list(scalars[:n]) + [0] * (bucket - n)
+        _PK_ROWS_LIVE.add(sum(len(r) for r in pk_rows))
+        _PK_ROWS_DISPATCHED.add(bucket * npk_pad)
 
         with span("bls.stage.host_pack"):
             packed = PT.g1_stack_packed(rows, npk_pad)
@@ -525,30 +534,32 @@ def rlc_combined_check(pk_rows, msgs, sig_pts, scalars, extra_pairs=(),
             hpt = _program_htc(u0, u1)
             _profile_sync(hpt)
 
-        # flat pairs axis: n item pairs + the folded signature pair
-        px_parts += [aggp[0][:n], jnp.asarray(_NEG_G1[0])]
-        py_parts += [aggp[1][:n], jnp.asarray(_NEG_G1[1])]
-        qx0_parts += [hpt[0][0][:n], s_aff[0][0]]
-        qx1_parts += [hpt[0][1][:n], s_aff[0][1]]
-        qy0_parts += [hpt[1][0][:n], s_aff[1][0]]
-        qy1_parts += [hpt[1][1][:n], s_aff[1][1]]
-        degen_parts += [np.asarray(agg_inf)[:n], np.asarray(s_inf)]
-    if extra_pairs:
-        eg1 = PT.g1_pack([p for p, _ in extra_pairs])
-        eg2 = PT.g2_pack([q for _, q in extra_pairs])
-        px_parts.append(eg1[0]); py_parts.append(eg1[1])
-        qx0_parts.append(eg2[0][0]); qx1_parts.append(eg2[0][1])
-        qy0_parts.append(eg2[1][0]); qy1_parts.append(eg2[1][1])
-        degen_parts.append(np.array(
-            [p.infinity or q.infinity for p, q in extra_pairs]))
-    cat = jnp.concatenate
-    px = cat([jnp.asarray(a) for a in px_parts])
-    py = cat([jnp.asarray(a) for a in py_parts])
-    q = ((cat([jnp.asarray(a) for a in qx0_parts]),
-          cat([jnp.asarray(a) for a in qx1_parts])),
-         (cat([jnp.asarray(a) for a in qy0_parts]),
-          cat([jnp.asarray(a) for a in qy1_parts])))
-    degen = jnp.asarray(np.concatenate(degen_parts))
+    with span("bls.stage.assemble"):
+        if n:
+            # flat pairs axis: n item pairs + the folded signature pair
+            px_parts += [aggp[0][:n], jnp.asarray(_NEG_G1[0])]
+            py_parts += [aggp[1][:n], jnp.asarray(_NEG_G1[1])]
+            qx0_parts += [hpt[0][0][:n], s_aff[0][0]]
+            qx1_parts += [hpt[0][1][:n], s_aff[0][1]]
+            qy0_parts += [hpt[1][0][:n], s_aff[1][0]]
+            qy1_parts += [hpt[1][1][:n], s_aff[1][1]]
+            degen_parts += [np.asarray(agg_inf)[:n], np.asarray(s_inf)]
+        if extra_pairs:
+            eg1 = PT.g1_pack([p for p, _ in extra_pairs])
+            eg2 = PT.g2_pack([q for _, q in extra_pairs])
+            px_parts.append(eg1[0]); py_parts.append(eg1[1])
+            qx0_parts.append(eg2[0][0]); qx1_parts.append(eg2[0][1])
+            qy0_parts.append(eg2[1][0]); qy1_parts.append(eg2[1][1])
+            degen_parts.append(np.array(
+                [p.infinity or q.infinity for p, q in extra_pairs]))
+        cat = jnp.concatenate
+        px = cat([jnp.asarray(a) for a in px_parts])
+        py = cat([jnp.asarray(a) for a in py_parts])
+        q = ((cat([jnp.asarray(a) for a in qx0_parts]),
+              cat([jnp.asarray(a) for a in qx1_parts])),
+             (cat([jnp.asarray(a) for a in qy0_parts]),
+              cat([jnp.asarray(a) for a in qy1_parts])))
+        degen = jnp.asarray(np.concatenate(degen_parts))
 
     with span("bls.stage.pairing"):
         return bool(np.asarray(

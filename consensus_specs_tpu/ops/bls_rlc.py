@@ -48,7 +48,7 @@ from consensus_specs_tpu.ops.bls12_381.fields import R_ORDER
 from consensus_specs_tpu.ops.bls12_381.hash_to_curve import hash_to_g2, DST_G2
 from consensus_specs_tpu.ops.bls12_381.pairing import multi_pairing_check
 from consensus_specs_tpu import supervisor
-from consensus_specs_tpu.utils.profiling import span
+from consensus_specs_tpu.obs.tracing import span
 
 SCALAR_BITS = 128
 _DOMAIN = b"CS_TPU_BLS_RLC_V1"
@@ -223,19 +223,23 @@ def _check_native(items, extra_checks, scalars):
 def _check_jax(items, extra_checks, scalars):
     from consensus_specs_tpu.ops import bls_jax
     n = len(items)
-    pk_rows, msgs, sig_pts = [], [], []
-    for pubkeys, message, signature in items:
-        if not pubkeys:
-            return None
-        rows = [bls_jax._packed_g1(pk) for pk in pubkeys]
-        if any(r is None for r in rows):
-            return None
-        spt = bls_jax._decompress_g2(signature)
-        if spt is None:
-            return None
-        pk_rows.append(rows)
-        msgs.append(bytes(message))
-        sig_pts.append(spt)
+    pk_rows = []
+    with span("bls.stage.pubkey_rows"):
+        for pubkeys, _, _ in items:
+            if not pubkeys:
+                return None
+            rows = [bls_jax._packed_g1(pk) for pk in pubkeys]
+            if any(r is None for r in rows):
+                return None
+            pk_rows.append(rows)
+    sig_pts = []
+    with span("bls.stage.sig_decompress"):
+        for _, _, signature in items:
+            spt = bls_jax._decompress_g2(signature)
+            if spt is None:
+                return None
+            sig_pts.append(spt)
+    msgs = [bytes(message) for _, message, _ in items]
     extra_pairs = []
     for (chk_pairs, _label), r in zip(extra_checks, scalars[n:]):
         for p, q in chk_pairs:
@@ -260,7 +264,8 @@ def combined_check(items, extra_checks, backend_name: str):
     per-lane path to report per-item results.
     """
     with span("bls.rlc.combine"):
-        scalars = derive_scalars(items, extra_checks)
+        with span("bls.rlc.scalars"):
+            scalars = derive_scalars(items, extra_checks)
         # cooperative deadline boundary between the (cheap) Fiat-Shamir
         # scalar stage and the MSM + pairing stage: an armed per-dispatch
         # budget (supervisor.deadline_scope in DeferredBatch.flush)

@@ -19,6 +19,7 @@ from typing import Sequence
 from consensus_specs_tpu import faults as _faults
 from consensus_specs_tpu import supervisor
 from consensus_specs_tpu.obs import registry as _obs_registry
+from consensus_specs_tpu.obs import tracing as _tracing
 from consensus_specs_tpu.utils import env_flags as _env_flags
 from consensus_specs_tpu.utils.lru import LRUDict
 from consensus_specs_tpu.ops.bls12_381 import ciphersuite as _py_backend
@@ -203,6 +204,10 @@ class DeferredBatch:
         return _pairing_check(pairs)
 
     def flush(self) -> bool:
+        with _tracing.span("bls.batch.flush"):
+            return self._flush()
+
+    def _flush(self) -> bool:
         items, keys = self.items, self.item_keys
         checks = self.pairing_checks
         self.items, self.item_keys, self.pairing_checks = [], [], []

@@ -109,7 +109,7 @@ HASH_FOREST = os.environ.get("CS_TPU_HASH_FOREST") != "0"
 
 # Telemetry span gates (``consensus_specs_tpu/obs``).  PROFILE turns on
 # hierarchical tracing spans (wall-clock span tree + flat aggregates,
-# ``obs.tracing`` / the ``utils/profiling`` aliases); TRACE additionally
+# ``obs.tracing``); TRACE additionally
 # attaches per-span counter deltas (a registry-wide counter diff on
 # every span entry/exit — more detail, more overhead) and implies
 # PROFILE.  Both default OFF: the disabled span path is a single

@@ -381,6 +381,65 @@ def test_numpy_kernel_rlc_differential():
     assert b"NUMPY-RLC-OK" in proc.stdout
 
 
+_NUMPY_PK_ROWS_CHECK = r"""
+import sys
+sys.path.insert(0, %(repo)r)
+from consensus_specs_tpu.ops.jax_bls.backend import NUMPY_KERNELS
+assert NUMPY_KERNELS
+from consensus_specs_tpu.obs import registry
+from consensus_specs_tpu.utils import bls
+
+bls.use_py()
+msg = b"rows" * 8
+pks = [bls.SkToPk(i) for i in (1, 2, 3)]
+agg = bls.Aggregate([bls.Sign(i, msg) for i in (1, 2, 3)])
+singles = [(bls.SkToPk(i), bytes([i]) * 32, bls.Sign(i, bytes([i]) * 32))
+           for i in (5, 6)]
+bls.use_jax()
+rows = registry.counter("bls.rlc.pk_rows")
+with bls.batched_verification() as batch:
+    assert bls.FastAggregateVerify(pks, msg, agg)
+    for pk, m, sig in singles:
+        assert bls.Verify(pk, m, sig)
+assert batch.flush()
+print("PK-ROWS", rows.value(kind="live"), rows.value(kind="dispatched"))
+"""
+
+
+def test_rlc_pk_rows_count_live_and_dispatched_rows():
+    """One RLC flush of a 3-key aggregate and two singles on the numpy
+    kernels (lane bucket = the 3 items): live rows are the 5 keys,
+    dispatched rows the 3 lanes x 8 (the padded pubkey width's floor)."""
+    import subprocess
+    import sys
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, CS_TPU_NUMPY_KERNELS="1")
+    proc = subprocess.run(
+        [sys.executable, "-c", _NUMPY_PK_ROWS_CHECK % {"repo": repo}],
+        env=env, capture_output=True, timeout=600, cwd=repo)
+    assert proc.returncode == 0, proc.stderr.decode()[-2000:]
+    assert b"PK-ROWS 5 24" in proc.stdout, proc.stdout.decode()[-500:]
+
+
+@pytest.mark.parametrize("kind", ["pubkey", "signature"])
+@pytest.mark.parametrize("position", [0, 1, 2])
+def test_check_jax_invalid_item_is_structural(kind, position, monkeypatch):
+    """The device combiner answers None (bisect) for an invalid pubkey
+    or signature wherever it sits, before any device work."""
+    from consensus_specs_tpu.ops import bls_jax
+
+    def no_device(*_args, **_kw):
+        raise AssertionError("an invalid batch reached the device")
+    items = _sig_items(3)
+    pks, msg, sig = items[position]
+    if kind == "pubkey":
+        items[position] = (pks[:-1] + [INF_PK], msg, sig)
+    else:
+        items[position] = (pks, msg, b"\x00" * 96)
+    monkeypatch.setattr(bls_jax, "rlc_combined_check", no_device)
+    assert bls_rlc._check_jax(items, (), [1, 2, 3]) is None
+
+
 # ---------------------------------------------------------------------------
 # Full-block differential: every enqueue site (proposer signature,
 # randao, attestations, altair sync aggregate) through one flush

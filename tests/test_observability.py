@@ -180,6 +180,61 @@ def test_span_exception_still_recorded():
     assert tracing.stats()["t.span.err"]["count"] == 1
 
 
+def _profiled_span_events(trace_dir, enabled):
+    """Nested ``t.prof.*`` spans inside a CPU profiler session; returns
+    the trace's host events of those names as (line, name, start, end)."""
+    import glob
+    import jax
+    from jax.profiler import ProfileData
+    tracing.enable(enabled, counters=False)
+    jax.profiler.start_trace(str(trace_dir))
+    try:
+        with tracing.span("t.prof.outer"):
+            with tracing.span("t.prof.inner"):
+                sum(range(1000))
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(trace_dir / "**" / "*.xplane.pb"), recursive=True)
+    return [(line.name, ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
+            for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events
+            if ev.name.startswith("t.prof.")]
+
+
+def test_enabled_spans_land_nested_in_a_profiler_trace(tmp_path):
+    events = _profiled_span_events(tmp_path, enabled=True)
+    by_name = {name: (line, s, e) for line, name, s, e in events}
+    assert len(events) == 2 and set(by_name) == {"t.prof.outer",
+                                                 "t.prof.inner"}
+    outer, inner = by_name["t.prof.outer"], by_name["t.prof.inner"]
+    assert outer[0] == inner[0]             # the calling thread's line
+    assert outer[1] <= inner[1] < inner[2] <= outer[2]
+    assert tracing.stats()["t.prof.inner"]["count"] == 1
+
+
+def test_disabled_spans_write_no_profiler_events(tmp_path):
+    assert _profiled_span_events(tmp_path, enabled=False) == []
+
+
+def test_enabled_spans_need_no_jax():
+    """``obs`` never imports jax itself: an enabled span in a process
+    without jax records its time and loads nothing."""
+    import subprocess
+    import sys
+    code = ("import sys\n"
+            "from consensus_specs_tpu.obs import tracing\n"
+            "tracing.enable(True)\n"
+            "with tracing.span('t.nojax'):\n"
+            "    pass\n"
+            "assert tracing.stats()['t.nojax']['count'] == 1\n"
+            "assert 'jax' not in sys.modules\n")
+    repo = __file__.rsplit("/tests/", 1)[0]
+    proc = subprocess.run([sys.executable, "-c", code], cwd=repo,
+                          capture_output=True, timeout=120)
+    assert proc.returncode == 0, proc.stderr.decode()[-2000:]
+
+
 # ---------------------------------------------------------------------------
 # real replay: span-tree shape + label attribution
 # ---------------------------------------------------------------------------
@@ -364,7 +419,7 @@ def test_report_renders_tree_and_metrics():
 
 
 # ---------------------------------------------------------------------------
-# env gates / profiling alias surface
+# env gates
 # ---------------------------------------------------------------------------
 
 def test_env_flags_registered():
@@ -394,23 +449,6 @@ def test_state_arrays_commit_span_recorded():
     finally:
         tracing.enable(False)
         arrays.use_auto()
-
-
-def test_profiling_module_is_thin_alias():
-    from consensus_specs_tpu.utils import profiling
-    assert profiling.span is tracing.span
-    assert profiling.stats is tracing.stats
-    profiling.enable(True)
-    try:
-        with profiling.span("t.alias"):
-            pass
-        st = profiling.stats()["t.alias"]
-        assert {"count", "total_s", "self_s", "mean_s", "max_s"} \
-            <= set(st)
-        assert "t.alias" in profiling.report()
-    finally:
-        profiling.enable(False)
-        profiling.reset()
 
 
 # ---------------------------------------------------------------------------

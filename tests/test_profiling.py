@@ -1,29 +1,29 @@
 """Profiling-span registry tests (SURVEY §5 tracing/profiling role)."""
 import time
 
-from consensus_specs_tpu.utils import profiling
+from consensus_specs_tpu.obs import export, tracing
 
 
 def test_spans_disabled_by_default_are_noop():
-    profiling.enable(False)
-    profiling.reset()
-    with profiling.span("x"):
+    tracing.enable(False)
+    tracing.reset()
+    with tracing.span("x"):
         pass
-    assert profiling.stats() == {}
+    assert tracing.stats() == {}
 
 
 def test_spans_aggregate():
-    profiling.enable(True)
-    profiling.reset()
+    tracing.enable(True)
+    tracing.reset()
     try:
         for _ in range(3):
-            with profiling.span("work"):
+            with tracing.span("work"):
                 time.sleep(0.01)
-        st = profiling.stats()["work"]
+        st = tracing.stats()["work"]
         assert st["count"] == 3
         assert st["total_s"] >= 0.03
         assert st["max_s"] >= st["mean_s"] > 0
-        assert "work" in profiling.report()
+        assert "work" in export.report()
     finally:
-        profiling.enable(False)
-        profiling.reset()
+        tracing.enable(False)
+        tracing.reset()
