@@ -153,9 +153,10 @@ _NEG_G1 = PT.g1_pack([-G1_GENERATOR])
 # a batch padded to its own lane bucket reuses the aggregation and
 # hash-to-curve programs the RLC flush of the same block compiled (131
 # items -> 256 lanes: 12 new programs for the bisect instead of 32).
-# 256 x 512-key rows is also about the widest pubkey tree sum that fits
-# one v5e's 16 GB (7.5 GB of temporaries).  On the host CPU compile time
-# dominates, so chunks stay at 8 lanes.  ``CS_TPU_BLS_BATCH`` overrides.
+# 256 x 512-key rows of the pubkey tree sum hold 3.8 GB of one v5e's
+# 16 GB (AOT for a described v5e: 3.6 GiB of temporaries, the rest its
+# arguments).  On the host CPU compile time dominates, so chunks stay at
+# 8 lanes.  ``CS_TPU_BLS_BATCH`` overrides.
 def bucket_b() -> int:
     """Most lanes per chunk; resolved at first dispatch (asking JAX for
     its backend initializes it, which importing this module must not)."""
@@ -188,8 +189,8 @@ _N_MIN = 8
 
 @kjit
 def _j_tree_sum(pk_pts):
-    """(B, N) projective G1 pytree -> (B,) unnormalized sum; one bounded
-    fori_loop program per (B, N) bucket."""
+    """(B, N) projective G1 pytree -> (B,) unnormalized sum; one halving
+    tree program per (B, N) bucket."""
     return PT.g1_tree_sum_batched(pk_pts)
 
 

@@ -169,21 +169,25 @@ def _scalar_mul(f, p, bits):
     return acc
 
 
-def _tree_sum(f, pts):
-    """Sum points over the leading axis by halving reductions (log depth)."""
-    n = jax.tree_util.tree_leaves(pts)[0].shape[0]
+def _tree_sum(f, pts, axis=0):
+    """Sum points over ``axis`` by halving reductions (log depth): each
+    level adds the first half of the live prefix to the second, so it
+    adds half as many points as the level before."""
+    lead = (slice(None),) * axis
+    n = jax.tree_util.tree_leaves(pts)[0].shape[axis]
     # pad to a power of two with identities (m - n < n always)
     m = 1 if n <= 1 else 1 << (n - 1).bit_length()
     if m != n:
         ident = _identity_like(f, pts)
         pts = jax.tree_util.tree_map(
-            lambda a, b: jnp.concatenate([a, b[: m - n]]), pts, ident)
+            lambda a, b: jnp.concatenate([a, b[lead + (slice(m - n),)]],
+                                         axis=axis), pts, ident)
     while m > 1:
         m //= 2
-        lo = jax.tree_util.tree_map(lambda a: a[:m], pts)
-        hi = jax.tree_util.tree_map(lambda a: a[m:], pts)
+        lo = jax.tree_util.tree_map(lambda a: a[lead + (slice(m),)], pts)
+        hi = jax.tree_util.tree_map(lambda a: a[lead + (slice(m, None),)], pts)
         pts = _complete_add(f, lo, hi)
-    return jax.tree_util.tree_map(lambda a: a[0], pts)
+    return jax.tree_util.tree_map(lambda a: a[lead + (0,)], pts)
 
 
 def _to_affine_host(f, p):
@@ -256,31 +260,13 @@ def g1_tree_sum(pts):
 def g1_tree_sum_batched(pts):
     """Sum over axis 1 of a (B, N, ...) packed batch, N a power of two.
 
-    Fixed-shape halving: every level is one full-width complete add of
-    the array against itself rolled by the (traced) stride, keeping only
-    the live prefix — so the whole reduction is ONE fori_loop program
-    with a ~13-mul body, not log2(N) differently-shaped adds.  (XLA:CPU
-    compile cost scales superlinearly with module size; this keeps the
-    aggregation program bounded for any N.)
+    A halving tree: log2(N) complete adds of N/2, N/4, ..., 1 points a
+    row, so the (B, N) grid is added once.  The module holds log2(N)
+    differently-shaped adds, which costs compile time, not run time.
     """
-    f = _FqOps
     n = jax.tree_util.tree_leaves(pts)[0].shape[1]
-    if n == 1:
-        return jax.tree_util.tree_map(lambda a: a[:, 0], pts)
     assert n & (n - 1) == 0, "pad the aggregation axis to a power of two"
-    levels = n.bit_length() - 1
-    lane = jnp.arange(n, dtype=jnp.uint32)
-
-    def body(k, arr):
-        stride = jnp.uint32(n) >> (k + 1)
-        rolled = jax.tree_util.tree_map(
-            lambda a: jnp.roll(a, -stride.astype(jnp.int32), axis=1), arr)
-        summed = _complete_add(f, arr, rolled)
-        keep = (lane < stride)[None, :]
-        return _select(f, keep, summed, arr)
-
-    out = lax.fori_loop(0, levels, body, pts)
-    return jax.tree_util.tree_map(lambda a: a[:, 0], out)
+    return _tree_sum(_FqOps, pts, axis=1)
 
 
 def g2_tree_sum(pts):

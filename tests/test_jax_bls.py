@@ -106,6 +106,75 @@ def test_g1_tree_sum_matches_oracle():
     assert got == exp
 
 
+def _tree_sum_rows(n):
+    """Four rows of ``n`` points: live points over identity padding at
+    the tail, all identity, P with -P, and one point twice.  The partner
+    sits n/2 along, so the first halving level adds P to -P (a sum that
+    is the identity) and Q to Q (the doubling case of the complete add)."""
+    r = random.Random(n)
+
+    def live(k):
+        return [G1_GENERATOR.mult(r.randrange(1, R_ORDER)) for _ in range(k)]
+
+    padded = live(max(1, n - 3))
+    rows = [padded + [G1Point.inf()] * (n - len(padded)),
+            [G1Point.inf()] * n, live(n), live(n)]
+    if n > 1:
+        rows[2][n // 2] = -rows[2][0]
+        rows[3][n // 2] = rows[3][0]
+    return rows
+
+
+def _check_g1_tree_sum_batched(n):
+    """``PT.g1_tree_sum_batched`` over a (4, n) grid, row by row against
+    the oracle's sum; jitted, or eager on the numpy mirror."""
+    from consensus_specs_tpu.ops.jax_bls.backend import kjit
+    rows = _tree_sum_rows(n)
+    packed = PT.g1_pack([p for row in rows for p in row])
+    grid = jax.tree_util.tree_map(
+        lambda a: a.reshape(len(rows), n, L.NLIMB), packed)
+    out = kjit(PT.g1_tree_sum_batched)(grid)
+    for i, row in enumerate(rows):
+        exp = G1Point.inf()
+        for p in row:
+            exp = exp + p
+        got = PT.g1_unpack(jax.tree_util.tree_map(lambda a: a[i], out))
+        assert got == exp, (n, i)
+
+
+_NUMPY_TREE_SUM_CHECK = r"""
+import sys
+sys.path[:0] = [%(repo)r, %(tests)r]
+from consensus_specs_tpu.ops.jax_bls.backend import NUMPY_KERNELS
+assert NUMPY_KERNELS
+import test_jax_bls
+test_jax_bls._check_g1_tree_sum_batched(%(n)d)
+print("NUMPY-TREE-SUM-OK")
+"""
+
+
+@pytest.mark.parametrize("n", [1, 2, 8, 64])
+@pytest.mark.parametrize("kernels", ["jax", "numpy"])
+def test_g1_tree_sum_batched_matches_oracle(kernels, n):
+    """The batched pubkey tree sum at B = 4: jitted here, and on the
+    numpy mirror in a subprocess (the switch is import-time)."""
+    if kernels == "jax":
+        _check_g1_tree_sum_batched(n)
+        return
+    import os
+    import subprocess
+    import sys
+    tests = os.path.dirname(os.path.abspath(__file__))
+    repo = os.path.dirname(tests)
+    proc = subprocess.run(
+        [sys.executable, "-c",
+         _NUMPY_TREE_SUM_CHECK % {"repo": repo, "tests": tests, "n": n}],
+        env=dict(os.environ, CS_TPU_NUMPY_KERNELS="1"),
+        capture_output=True, timeout=300, cwd=repo)
+    assert proc.returncode == 0, proc.stderr.decode()[-2000:]
+    assert b"NUMPY-TREE-SUM-OK" in proc.stdout
+
+
 def test_g2_scalar_mul_matches_oracle():
     k = 98765
     bits = np.array([int(c) for c in bin(k)[2:]], dtype=np.uint32)

@@ -79,10 +79,13 @@ def _g2_affine(lanes):
 
 def test_pubkey_aggregation(one_chip):
     """The per-item pubkey tree sum over (256, 512) rows: the largest
-    device buffer of the flush (several GiB of temporaries)."""
+    device buffer of the flush.  The halving tree holds ~3.8 GB in all;
+    a form that adds the full grid at every level (~7.5 GB) fails the
+    band."""
     from consensus_specs_tpu.ops import bls_jax
     pk = tuple(_u32(LANES, PUBKEYS, NLIMB) for _ in range(3))
-    assert _compile(bls_jax._j_tree_sum, _on(one_chip, pk)) > 2**30
+    used = _compile(bls_jax._j_tree_sum, _on(one_chip, pk))
+    assert 2**30 < used < 5 * 2**30, used
 
 
 def test_rlc_scalar_multiplication(one_chip):
