@@ -20,7 +20,13 @@ def register_fork(name):
         from consensus_specs_tpu.obs import install_tracing
         from consensus_specs_tpu.ops.att_prep import install_att_prep
         from consensus_specs_tpu.das.engine import install_das_accel
+        from consensus_specs_tpu.ops.block_kernels import \
+            install_block_kernels
+        from consensus_specs_tpu.ops.committees import \
+            install_committee_shuffle
         install_att_prep(cls)
+        install_committee_shuffle(cls)
+        install_block_kernels(cls)
         install_das_accel(cls)
         install_tracing(cls)
         _REGISTRY[name] = cls
@@ -96,6 +102,8 @@ def use_compiled_registry():
     importlib.invalidate_caches()  # compiled/ may have just been created
     from consensus_specs_tpu.obs import install_tracing
     from consensus_specs_tpu.ops.att_prep import install_att_prep
+    from consensus_specs_tpu.ops.block_kernels import install_block_kernels
+    from consensus_specs_tpu.ops.committees import install_committee_shuffle
     from consensus_specs_tpu.ops.epoch_kernels import install_vectorized_epoch
     from consensus_specs_tpu.forkchoice.proto_array import (
         install_forkchoice_accel)
@@ -105,11 +113,14 @@ def use_compiled_registry():
         importlib.reload(mod)
         cls = getattr(mod, f"Compiled{fork.capitalize()}Spec")
         # compiled method bodies are emitted verbatim from the markdown,
-        # so the vectorized-epoch, attestation message-prep, proto-array
-        # fork-choice and DAS sampling dispatches (and the tracing
-        # spans) wrap them from outside
+        # so the vectorized-epoch, attestation message-prep, committee
+        # shuffle, block-operation, proto-array fork-choice and DAS
+        # sampling dispatches (and the tracing spans) wrap them from
+        # outside
         install_vectorized_epoch(cls)
         install_att_prep(cls)
+        install_committee_shuffle(cls)
+        install_block_kernels(cls)
         install_forkchoice_accel(cls)
         install_das_accel(cls)
         install_tracing(cls)

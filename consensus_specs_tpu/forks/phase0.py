@@ -23,6 +23,7 @@ from consensus_specs_tpu.utils.ssz import (
 )  # noqa: F401 (compiled-spec namespace)
 from consensus_specs_tpu.utils import bls
 from consensus_specs_tpu.utils.ssz.forest import hash_forest
+from consensus_specs_tpu.obs.tracing import span
 from consensus_specs_tpu.ops import epoch_kernels
 from consensus_specs_tpu.state import arrays as state_arrays
 from . import register_fork
@@ -701,7 +702,7 @@ class Phase0Spec(ValidatorGuideMixin, ForkChoiceMixin):
         batch.assert_valid()
         # Verify state root
         if validate_result:
-            with hash_forest():
+            with hash_forest(), span("st.state_root"):
                 assert block.state_root == hash_tree_root(state)
 
     def verify_block_signature(self, state, signed_block) -> bool:
@@ -735,7 +736,7 @@ class Phase0Spec(ValidatorGuideMixin, ForkChoiceMixin):
         # Cache state root.  The forest scope batches the dirty re-hash
         # level-aligned across every mutated tree of the state (balances,
         # roots vectors, registry, ...) — see utils/ssz/forest.py.
-        with hash_forest():
+        with hash_forest(), span("st.state_root"):
             previous_state_root = hash_tree_root(state)
         state.state_roots[state.slot % self.SLOTS_PER_HISTORICAL_ROOT] = previous_state_root
         # Cache latest block header state root

@@ -27,28 +27,33 @@ import functools
 
 from . import tracing
 
-# The traced spec surface: block/epoch-granularity transition stages.
-# Order is irrelevant; nesting comes from runtime call structure.
-TRACED_METHODS = (
-    "state_transition",
-    "process_slots",
-    "process_slot",
-    "process_epoch",
-    "process_block",
-    "process_operations",
-    "on_block",
-    "on_attestation",
-    "on_tick",
-)
+# The traced spec surface, method -> span name: block/epoch-granularity
+# transition stages (``st.*``), the block operations whose cost scales
+# with the registry, and the fork-choice handlers.  Order is
+# irrelevant; nesting comes from runtime call structure.
+TRACED_METHODS = {
+    "state_transition": "st.state_transition",
+    "process_slots": "st.process_slots",
+    "process_slot": "st.process_slot",
+    "process_epoch": "st.process_epoch",
+    "process_block": "st.process_block",
+    "process_operations": "st.process_operations",
+    "process_attestation": "st.block.attestations",
+    "process_sync_aggregate": "st.block.sync_aggregate",
+    "process_withdrawals": "st.block.withdrawals",
+    "on_block": "on_block",
+    "on_attestation": "on_attestation",
+    "on_tick": "on_tick",
+}
 
 
 def install_tracing(cls) -> None:
     """Wrap ``cls``'s own transition-stage methods in tracing spans."""
-    for name in TRACED_METHODS:
+    for name, span_name in TRACED_METHODS.items():
         fn = cls.__dict__.get(name)
         if fn is None or getattr(fn, "_obs_span_wrapper", False):
             continue
-        setattr(cls, name, _make_wrapper(name, fn))
+        setattr(cls, name, _make_wrapper(span_name, fn))
 
 
 def _make_wrapper(name, fn):

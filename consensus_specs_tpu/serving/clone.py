@@ -24,7 +24,11 @@ policies:
   shadowed by properties: element copies and the tree memcpy happen on
   first touch, against a strong reference to the frozen source.  A
   snapshot that is never mutated or re-merkleized (the common fate of
-  ``store.block_states`` entries) never pays for either.
+  ``store.block_states`` entries) never pays for either.  Elements
+  whose fields are all immutable leaves (``Validator``) are copied with
+  one C-level ``__dict__`` copy each, so a first read of a 2^20-entry
+  registry costs one pass of object allocations, not a ``copy()`` call
+  per field of every validator.
 * **eager** — everything else (nested containers, bitfields, small
   sequences): the ordinary ``copy()``.
 
@@ -42,6 +46,9 @@ holds there.  Counters: ``serving.clones``, per-mode
 ``serving.clone_fields``, and ``serving.materializations`` (how much
 of the deferred work was ever actually paid).
 """
+
+import gc
+import weakref
 
 from consensus_specs_tpu.obs import registry as obs_registry
 from consensus_specs_tpu.utils import env_flags
@@ -75,6 +82,7 @@ _TREE_UNSET = object()
 
 _lazy_cache = {}            # concrete sequence class -> lazy subclass
 _fast_cache = {}            # concrete sequence class -> fast subclass
+_leaf_cache = {}            # element class -> all fields immutable leaves
 
 
 def _lazy_min() -> int:
@@ -92,6 +100,45 @@ def _flush_source_dirt(src) -> None:
     frozen-source guard."""
     if getattr(src, "_tree", None) is not None and getattr(src, "_dirty", None):
         src._tree_root()
+
+
+def _leaf_container(et) -> bool:
+    """``et`` is a container whose every field is an immutable leaf, so
+    its ``copy()`` only re-binds the same field values."""
+    leaf = _leaf_cache.get(et)
+    if leaf is None:
+        leaf = _leaf_cache[et] = issubclass(et, Container) and all(
+            issubclass(t, _IMMUTABLE_ELEMS) for t in et._fields.values())
+    return leaf
+
+
+def _copy_elems(items, owner) -> list:
+    """``[x.copy() for x in items]``, each owned by ``owner`` at its index.
+    A leaf container's copy is its ``__dict__`` copied at C level: the
+    same field values and memoized root ``copy()`` gives, ~7x cheaper."""
+    if not _leaf_container(type(owner).elem_type):
+        out = [x.copy() for x in items]
+        for i, x in enumerate(out):
+            _set_owner(x, owner, i)
+        return out
+    ref = weakref.ref(owner)
+    new = object.__new__
+    out = []
+    # the pass makes no reference cycles: spare it the collector's
+    # rescans of a heap that grows by a registry (half its cost)
+    collecting = gc.isenabled()
+    gc.disable()
+    try:
+        for i, x in enumerate(items):
+            y = new(x.__class__)
+            d = y.__dict__
+            d.update(x.__dict__)
+            d["_owner"] = (ref, i)
+            out.append(y)
+    finally:
+        if collecting:
+            gc.enable()
+    return out
 
 
 def _lazy_class(cls):
@@ -116,10 +163,7 @@ def _lazy_class(cls):
             d["_lz_src"] = None
 
     def _materialize(self):
-        src = _check_src(self)
-        items = [x.copy() for x in src._items]
-        for i, x in enumerate(items):
-            _set_owner(x, self, i)
+        items = _copy_elems(_check_src(self)._items, self)
         self.__dict__["_lz_items"] = items
         _C_MAT_ITEMS.add()
         _maybe_release(self)
@@ -165,10 +209,7 @@ def _lazy_class(cls):
         # Materialized: behave exactly like the base-class copy, and
         # produce a PLAIN instance so laziness doesn't nest.
         new = object.__new__(cls)
-        items = [x.copy() for x in d["_lz_items"]]
-        object.__setattr__(new, "_items", items)
-        for i, x in enumerate(items):
-            _set_owner(x, new, i)
+        object.__setattr__(new, "_items", _copy_elems(d["_lz_items"], new))
         _SequenceBase._copy_tree_into(self, new)
         return new
 

@@ -260,17 +260,18 @@ def _replayed_snapshot(slots=8, validators=32):
 def test_state_transition_span_tree_shape():
     snap = _replayed_snapshot()
     tree = snap["spans"]
-    st = tree["state_transition"]
+    st = tree["st.state_transition"]
     assert st["count"] == 8
-    slots_node = st["children"]["process_slots"]
+    slots_node = st["children"]["st.process_slots"]
     assert slots_node["count"] == 8
-    assert "process_slot" in slots_node["children"]
-    assert "process_epoch" in slots_node["children"]
-    assert "process_block" in st["children"]
-    # the batched merkleization shows up inside the transition
-    assert "hash_forest.flush" in st["children"] \
-        or "hash_forest.flush" in slots_node["children"]["process_slot"][
-            "children"]
+    assert "st.process_slot" in slots_node["children"]
+    assert "st.process_epoch" in slots_node["children"]
+    assert "st.process_block" in st["children"]
+    # the batched merkleization shows up inside the state-root spans
+    slot_root = slots_node["children"]["st.process_slot"]["children"][
+        "st.state_root"]
+    assert "hash_forest.flush" in slot_root["children"] \
+        or "hash_forest.flush" in st["children"]["st.state_root"]["children"]
     # fork-choice handlers traced too (replay feeds a store)
     assert tree["on_block"]["count"] == 8
     # per-span counter deltas attached under CS_TPU_TRACE semantics

@@ -266,6 +266,29 @@ def test_lazy_clone_defers_until_touched(spec):
     assert delta["serving.materializations{stage=items}"] == 0
 
 
+def test_lazy_clone_copies_registry_elements_like_copy(spec):
+    """A materialized registry holds what ``copy()`` would: equal
+    fields and memoized roots, each element owned by the clone at its
+    index; a write through one moves neither the source nor its root."""
+    state = _genesis(spec)
+    root = bytes(hash_tree_root(state))
+    cl = clone_state(state)
+    src, got = state.validators, cl.validators
+    for i in range(len(src)):
+        a, b = src[i], got[i]
+        assert a is not b and type(a) is type(b)
+        assert all(getattr(a, f) == getattr(b, f) for f in type(a)._fields)
+        assert object.__getattribute__(b, "_root_cache") == \
+            object.__getattribute__(a, "_root_cache")
+        assert b._owner[0]() is got and b._owner[1] == i
+    ref = state.copy()
+    for st in (ref, cl):
+        st.validators[3].slashed = True
+    assert not src[3].slashed
+    assert bytes(hash_tree_root(state)) == root
+    assert bytes(hash_tree_root(cl)) == bytes(hash_tree_root(ref)) != root
+
+
 def test_lazy_clone_frozen_source_contract(spec):
     """Mutating the source after a chunk-level clone must fail the
     clone's deferred touches loudly — never materialize drifted data."""
