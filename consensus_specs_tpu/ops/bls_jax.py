@@ -20,6 +20,7 @@ Shape discipline: batch and aggregate axes are padded to powers of two so
 the number of compiled program variants stays O(log n); padding lanes are
 degenerate pairs that contribute the identity to the pairing product.
 """
+import threading
 from collections import OrderedDict
 
 import numpy as np
@@ -32,6 +33,7 @@ from consensus_specs_tpu.obs import registry, tracing
 from consensus_specs_tpu.obs.tracing import span
 from consensus_specs_tpu.ops.bls12_381.curve import (
     G1Point, G2Point, G1_GENERATOR, g1_from_compressed, g2_from_compressed)
+from consensus_specs_tpu.ops.jax_bls import limbs as L
 from consensus_specs_tpu.ops.jax_bls import points as PT
 from consensus_specs_tpu.ops.jax_bls import pairing as PR
 from consensus_specs_tpu.ops.jax_bls import htc as HTC
@@ -73,12 +75,103 @@ class _LRU(OrderedDict):
             self.popitem(last=False)
 
 
+# Pubkey-table rows written: cache misses that packed a key's row.  Zero
+# in a steady window, where the warm-up already packed every key.
+_PK_TABLE_ROWS = registry.counter("bls.pk_table.rows_added").labels()
+_Z_ROWS = np.stack([L.ZERO, L.ONE_M])      # z of slot 0, z of a live slot
+
+
+class _PubkeyCache(_LRU):
+    """bytes48 -> ``[G1Point|None, slot|None]``: the oracle point, and
+    the key's row in ONE contiguous table of packed affine Montgomery
+    limbs (``x``, ``y``: (capacity, 24) uint32).  A flush gathers its
+    (lanes x width) pubkey grid from the table with one fancy index per
+    coordinate (:meth:`grid`), not a stack of a row pair per key.
+
+    Slot 0 is the identity (x 0, y mont(1), z 0); padding lanes and keys
+    index it.  A valid key gets a slot the first time it is packed (its
+    one int->limb conversion for the cache's lifetime), keys that fail
+    KeyValidate never get one, and an evicted key's slot goes back on
+    the free list.  Capacity doubles as keys arrive, up to
+    ``maxsize + 1``.  Hits are lock-free; admission, packing and
+    eviction hold ``_lock`` (the serving pipeline flushes on a worker
+    thread)."""
+
+    def __init__(self, maxsize, capacity=1 << 10):
+        super().__init__(maxsize)
+        self._lock = threading.Lock()
+        self._free = []
+        self._next = 1
+        self.x = np.zeros((min(capacity, maxsize + 1), L.NLIMB), np.uint32)
+        self.y = np.zeros_like(self.x)
+        self.y[0] = L.ONE_M
+
+    def popitem(self, last=True):
+        key, entry = super().popitem(last)
+        if entry[1] is not None:
+            self._free.append(entry[1])
+            entry[1] = None     # re-admitted, the entry packs anew
+        return key, entry
+
+    def admit(self, key, entry):
+        """Insert ``entry`` unless ``key`` is resident; the resident
+        entry either way."""
+        with self._lock:
+            cur = self.get(key)
+            if cur is None:
+                self.put(key, entry)
+                cur = entry
+            return cur
+
+    def pack(self, key, entry) -> int:
+        """The slot of a valid key, its row written on first use."""
+        with self._lock:
+            cur = self.get(key)
+            if cur is None:             # evicted since it was looked up
+                self.put(key, entry)
+            elif cur is not entry:      # re-admitted by another thread
+                entry = cur
+            if entry[1] is None:
+                if self._free:
+                    slot = self._free.pop()
+                else:
+                    slot = self._next
+                    self._next += 1
+                    if slot == len(self.x):
+                        self._grow()
+                pt = entry[0]
+                self.x[slot] = L.fq_const(pt.x.n)
+                self.y[slot] = L.fq_const(pt.y.n)
+                entry[1] = slot
+                _PK_TABLE_ROWS.add()
+            return entry[1]
+
+    def _grow(self):
+        cap = min(2 * len(self.x), self.maxsize + 1)
+        for name in ("x", "y"):
+            old = getattr(self, name)
+            new = np.zeros((cap, L.NLIMB), np.uint32)
+            new[:len(old)] = old
+            setattr(self, name, new)
+
+    def grid(self, slot_rows, lanes: int, width: int) -> tuple:
+        """Per-lane slot lists -> the (lanes, width) projective pytree
+        ((lanes, width, 24) uint32 x3) of their points, every lane
+        identity-padded: live (x, y, mont(1)), slot 0 (0, mont(1), 0) —
+        ``PT.g1_pack``'s encoding."""
+        idx = np.zeros((lanes, width), np.int32)
+        for i, row in enumerate(slot_rows):
+            idx[i, :len(row)] = row
+        return (np.take(self.x, idx, axis=0), np.take(self.y, idx, axis=0),
+                np.take(_Z_ROWS, np.minimum(idx, 1), axis=0))
+
+
 # Pubkeys are bounded by the validator registry; signatures are unique per
 # message so their cache mainly serves immediate re-verification.  One
-# cache entry per pubkey holds BOTH views — the oracle G1Point and the
-# lazily-packed Montgomery limb rows the device path stacks — so the
+# cache entry per pubkey holds BOTH views — the oracle G1Point and its
+# slot in the packed table the device path gathers from — so the
 # registry is never resident twice.
-_g1_cache = _LRU(1 << 21)
+_g1_cache = _PubkeyCache(1 << 21)
 _g2_cache = _LRU(1 << 14)
 
 
@@ -91,19 +184,26 @@ def _native():
 
 
 def _g1_entry(data: bytes):
-    """bytes48 -> [G1Point|None, packed|None] (KeyValidate semantics:
-    non-canonical, off-curve, out-of-subgroup and identity are None)."""
+    """bytes48 -> [G1Point|None, slot|None] (KeyValidate semantics:
+    non-canonical, off-curve, out-of-subgroup and identity are None),
+    touched as the cache's most recent entry."""
     key = bytes(data)
-    if key not in _g1_cache:
+    entry = _g1_cache.get(key)
+    if entry is not None:
         try:
-            pt = g1_from_compressed(key)
-            nb = _native()
-            ok = (not pt.infinity) and (
-                nb.KeyValidate(key) if nb else pt.in_subgroup())
-            _g1_cache.put(key, [pt if ok else None, None])
-        except Exception:
-            _g1_cache.put(key, [None, None])
-    return _g1_cache[key]
+            _g1_cache.move_to_end(key)
+            return entry
+        except KeyError:        # evicted by another thread meanwhile
+            return _g1_cache.admit(key, entry)
+    try:
+        pt = g1_from_compressed(key)
+        nb = _native()
+        ok = (not pt.infinity) and (
+            nb.KeyValidate(key) if nb else pt.in_subgroup())
+        entry = [pt if ok else None, None]
+    except Exception:
+        entry = [None, None]
+    return _g1_cache.admit(key, entry)
 
 
 def _decompress_g1(data: bytes):
@@ -126,17 +226,32 @@ def _decompress_g2(data: bytes):
     return _g2_cache[key]
 
 
-def _packed_g1(data: bytes):
-    """bytes48 -> (x_limbs, y_limbs) numpy rows (affine, Montgomery) or
-    None if the key fails KeyValidate.  The python int->limb conversion
-    costs ~50us/point and registry pubkeys repeat across every block, so
-    the rows are packed once and cached alongside the point."""
+def _g1_slot(data: bytes):
+    """bytes48 -> its pubkey-table slot, or None if the key fails
+    KeyValidate.  The python int->limb conversion costs ~50us/point and
+    registry pubkeys repeat across every block, so a key's row is packed
+    once and stays in the table while its entry is cached."""
     entry = _g1_entry(data)
     if entry[0] is None:
         return None
-    if entry[1] is None:
-        entry[1] = PT.g1_pack_affine_rows(entry[0])
-    return entry[1]
+    slot = entry[1]
+    return slot if slot is not None else _g1_cache.pack(bytes(data), entry)
+
+
+def _slot_rows(pubkey_lists) -> list:
+    """Per item, its keys' pubkey-table slots, or None where a key fails
+    KeyValidate.  Each key is touched as the cache's most recent, so no
+    key of the batch is evicted (and its slot handed on) before the
+    batch's grid is gathered — unless the batch alone carries more keys
+    than the cache holds, which raises."""
+    if sum(map(len, pubkey_lists)) > _g1_cache.maxsize:
+        raise ValueError("a batch carries more pubkeys than the pubkey "
+                         f"cache holds ({_g1_cache.maxsize})")
+    out = []
+    for pubkeys in pubkey_lists:
+        slots = [_g1_slot(pk) for pk in pubkeys]
+        out.append(None if None in slots else slots)
+    return out
 
 
 def _pow2(n: int) -> int:
@@ -303,15 +418,15 @@ def verify_aggregates_batch(items) -> list:
 def _verify_aggregates_batch(items) -> list:
     results_host = [None] * len(items)
     rows = []
-    pk_pts = [[_packed_g1(pk) for pk in pubkeys] for pubkeys, _, _ in items]
+    pk_slots = _slot_rows([pubkeys for pubkeys, _, _ in items])
     with span("bls.stage.sig_decompress"):
         sig_pts = [_decompress_g2(sig) for _, _, sig in items]
-    for idx, ((pubkeys, msg, _), pts, spt) in enumerate(
-            zip(items, pk_pts, sig_pts)):
-        if len(pubkeys) == 0 or any(p is None for p in pts) or spt is None:
+    for idx, ((pubkeys, msg, _), slots, spt) in enumerate(
+            zip(items, pk_slots, sig_pts)):
+        if len(pubkeys) == 0 or slots is None or spt is None:
             results_host[idx] = False
             continue
-        rows.append((idx, pts, bytes(msg), spt))
+        rows.append((idx, slots, bytes(msg), spt))
     if not rows:
         return [bool(r) for r in results_host]
 
@@ -326,20 +441,17 @@ def _verify_aggregates_batch(items) -> list:
     for start in range(0, len(rows), B):
         chunk = rows[start:start + B]
         n_pad = max(_N_MIN, _pow2(max(len(r[1]) for r in chunk)))
-        sig_pts, msgs, pk_rows = [], [], []
-        for _, pts, msg, spt in chunk:
-            pk_rows.append(pts)
+        sig_pts, msgs = [], []
+        for _, _, msg, spt in chunk:
             sig_pts.append(spt)
             msgs.append(msg)
         for _ in range(B - len(chunk)):   # degenerate padding rows
-            pk_rows.append([])
             sig_pts.append(G2Point.inf())
             msgs.append(b"")
 
         with span("bls.stage.host_pack"):
-            packed = PT.g1_stack_packed(pk_rows, n_pad)
-            pk_pts = jax.tree_util.tree_map(
-                lambda a: a.reshape((B, n_pad) + a.shape[1:]), packed)
+            pk_pts = tuple(jnp.asarray(a) for a in _g1_cache.grid(
+                [r[1] for r in chunk], B, n_pad))
         with span("bls.stage.hash_to_field"):
             u0, u1 = HTC.hash_to_field_host(msgs)
         sig_packed = PT.g2_pack(sig_pts)
@@ -475,12 +587,12 @@ _PK_ROWS_DISPATCHED = registry.counter("bls.rlc.pk_rows").labels(
     kind="dispatched")
 
 
-def rlc_combined_check(pk_rows, msgs, sig_pts, scalars, extra_pairs=(),
+def rlc_combined_check(pk_slots, msgs, sig_pts, scalars, extra_pairs=(),
                        mesh_devices=None) -> bool:
     """One product pairing for a whole flushed batch.
 
-    ``pk_rows``: per item, the list of packed affine pubkey rows (already
-    KeyValidate-checked by the caller); ``msgs``: per-item message bytes;
+    ``pk_slots``: per item, its pubkeys' slots in the pubkey table
+    (``_slot_rows``: already KeyValidate-checked); ``msgs``: per-item message bytes;
     ``sig_pts``: per-item oracle G2Points (subgroup-checked; infinity
     allowed); ``scalars``: the per-item 128-bit RLC coefficients;
     ``extra_pairs``: pre-scaled oracle ``(G1Point, G2Point)`` pairs
@@ -489,23 +601,21 @@ def rlc_combined_check(pk_rows, msgs, sig_pts, scalars, extra_pairs=(),
     signature MSM shards its point axis across it through
     ``parallel.sharded_verify.make_sharded_g2_msm``.
     """
-    n = len(pk_rows)
+    n = len(pk_slots)
     assert n == len(msgs) == len(sig_pts) and len(scalars) >= n
     px_parts, py_parts = [], []
     qx0_parts, qx1_parts, qy0_parts, qy1_parts = [], [], [], []
     degen_parts = []
     if n:
         bucket = PR.lane_bucket(n)
-        npk_pad = max(_N_MIN, _pow2(max(len(r) for r in pk_rows)))
-        rows = list(pk_rows) + [[]] * (bucket - n)
+        npk_pad = max(_N_MIN, _pow2(max(len(r) for r in pk_slots)))
         pad_scalars = list(scalars[:n]) + [0] * (bucket - n)
-        _PK_ROWS_LIVE.add(sum(len(r) for r in pk_rows))
+        _PK_ROWS_LIVE.add(sum(len(r) for r in pk_slots))
         _PK_ROWS_DISPATCHED.add(bucket * npk_pad)
 
         with span("bls.stage.host_pack"):
-            packed = PT.g1_stack_packed(rows, npk_pad)
-            pk_pts = jax.tree_util.tree_map(
-                lambda a: a.reshape((bucket, npk_pad) + a.shape[1:]), packed)
+            pk_pts = tuple(jnp.asarray(a) for a in _g1_cache.grid(
+                pk_slots, bucket, npk_pad))
             sig_packed = PT.g2_pack(list(sig_pts)
                                     + [G2Point.inf()] * (bucket - n))
             bits = jnp.asarray(_bits_msb(pad_scalars, RLC_SCALAR_BITS))

@@ -223,15 +223,12 @@ def _check_native(items, extra_checks, scalars):
 def _check_jax(items, extra_checks, scalars):
     from consensus_specs_tpu.ops import bls_jax
     n = len(items)
-    pk_rows = []
     with span("bls.stage.pubkey_rows"):
-        for pubkeys, _, _ in items:
-            if not pubkeys:
-                return None
-            rows = [bls_jax._packed_g1(pk) for pk in pubkeys]
-            if any(r is None for r in rows):
-                return None
-            pk_rows.append(rows)
+        if any(not pubkeys for pubkeys, _, _ in items):
+            return None
+        pk_slots = bls_jax._slot_rows([pubkeys for pubkeys, _, _ in items])
+        if None in pk_slots:
+            return None
     sig_pts = []
     with span("bls.stage.sig_decompress"):
         for _, _, signature in items:
@@ -244,10 +241,10 @@ def _check_jax(items, extra_checks, scalars):
     for (chk_pairs, _label), r in zip(extra_checks, scalars[n:]):
         for p, q in chk_pairs:
             extra_pairs.append((_scale_g1_host(p, r), q))
-    if not pk_rows and not extra_pairs:
+    if not pk_slots and not extra_pairs:
         return True
     return bls_jax.rlc_combined_check(
-        pk_rows, msgs, sig_pts, scalars[:n], extra_pairs=extra_pairs,
+        pk_slots, msgs, sig_pts, scalars[:n], extra_pairs=extra_pairs,
         mesh_devices=mesh_devices())
 
 

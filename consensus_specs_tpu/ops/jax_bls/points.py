@@ -13,7 +13,6 @@ tree-reduction over these complete adds, so aggregating 2048 attestation
 pubkeys is a depth-11 vectorized reduction rather than a serial loop
 (reference hot path ``eth2spec/utils/bls.py:133-143``).
 """
-import numpy as np
 import jax  # tree_util only; array ops ride the backend switch
 from .backend import xp as jnp, lax, kjit
 
@@ -386,28 +385,3 @@ def g1_unpack(p) -> G1Point:
 
 def g2_unpack(p) -> G2Point:
     return _to_affine_host(_Fq2Ops, p)
-
-
-def g1_pack_affine_rows(pt: G1Point) -> tuple:
-    """Host-side: one affine (non-identity) oracle point -> its packed
-    Montgomery (x, y) limb rows.  The z row is implied mont(1) — see
-    g1_stack_packed, which owns the projective encoding."""
-    return (L.fq_const(pt.x.n), L.fq_const(pt.y.n))
-
-
-def g1_stack_packed(rows, n_pad: int) -> tuple:
-    """Host-side: rows of g1_pack_affine_rows outputs -> batched packed
-    projective pytree ((N,24) x3), each row identity-padded to ``n_pad``.
-
-    Owns the projective encoding next to g1_pack: live points are
-    (x, y, mont(1)); identity is (0, mont(1), 0).
-    """
-    zero_row, one_row = L.ZERO, L.ONE_M
-    xs, ys, zs = [], [], []
-    for row in rows:
-        pad = n_pad - len(row)
-        xs.extend([p[0] for p in row] + [zero_row] * pad)
-        ys.extend([p[1] for p in row] + [one_row] * pad)
-        zs.extend([one_row] * len(row) + [zero_row] * pad)
-    return (jnp.asarray(np.stack(xs)), jnp.asarray(np.stack(ys)),
-            jnp.asarray(np.stack(zs)))
