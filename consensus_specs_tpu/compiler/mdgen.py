@@ -46,8 +46,9 @@ signatures from one context unusable in another.""",
       "compute_activation_exit_epoch", "compute_fork_data_root",
       "compute_fork_digest", "compute_domain", "compute_signing_root"]),
     ("State accessors", """
-Read-only views over the state.  The committee/proposer accessors memoize
-on the registry root — identical lookups dominate block processing.""",
+Read-only views over the state.  The active-index, proposer and
+total-balance accessors memoize on the registry root — identical lookups
+dominate block processing.""",
      ["get_current_epoch", "get_previous_epoch", "get_block_root",
       "get_block_root_at_slot", "get_randao_mix",
       "get_active_validator_indices", "get_validator_churn_limit",

@@ -1,8 +1,8 @@
 """DAS engine dispatch: live switch, counted fallbacks, supervision.
 
 Wraps the eip7594 spec surface from outside (``install_das_accel``,
-applied by ``forks.register_fork`` and ``forks.use_compiled_registry``
-— the markdown bodies stay spec-shaped):
+applied to both ladders by ``forks.install_engines`` — the markdown
+bodies stay spec-shaped):
 
 ``verify_cell_proof_batch`` -> :func:`kernels.verify_cell_proof_batch`
     the whole batch in ONE product pairing check (zero own pairings
@@ -236,9 +236,8 @@ def install_das_accel(cls) -> None:
     """Wrap ``cls``'s own ``verify_cell_proof_batch`` and
     ``recover_polynomial`` with the engine dispatch.  Only methods
     defined on ``cls`` itself are wrapped (delta forks inherit the
-    wrapped eip7594 surface); wrapping is idempotent.  Applied to the
-    hand-written ladder by ``forks.register_fork`` and to each
-    markdown-compiled class by ``forks.use_compiled_registry``."""
+    wrapped eip7594 surface); wrapping is idempotent.  Applied to every
+    fork class of both ladders by ``forks.install_engines``."""
     fn = cls.__dict__.get("verify_cell_proof_batch")
     if fn is not None and not getattr(fn, "_das_wrapper", False):
         @functools.wraps(fn)

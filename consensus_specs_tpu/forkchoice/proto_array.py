@@ -45,11 +45,10 @@ Layering mirrors ``ops/epoch_kernels``:
   install_forkchoice_accel(cls)           wraps a spec class's
       fork-choice surface with the dispatch plus the store-attached
       bookkeeping (incremental children index, memoized ancestor
-      walks).  Applied to the hand-written ``ForkChoiceMixin`` at
-      definition time and to each markdown-compiled class by
-      ``forks.use_compiled_registry`` (compiled method bodies are
-      emitted verbatim from the spec text and cannot carry dispatch
-      calls).
+      walks).  Applied by ``forks.install_engines`` to the
+      hand-written ``ForkChoiceMixin`` at definition time and to each
+      markdown-compiled class, so neither ladder's method bodies carry
+      dispatch calls.
 """
 import functools
 
@@ -706,13 +705,12 @@ def attach_store_accel(spec, store) -> None:
 def install_forkchoice_accel(cls) -> None:
     """Wrap ``cls``'s own fork-choice methods with the proto-array
     dispatch and the store-attached bookkeeping (incremental
-    parent->children index, memoized ``get_ancestor``).  Used for both
-    ladders: the hand-written ``ForkChoiceMixin`` (at definition time)
-    and each markdown-compiled class (``forks.use_compiled_registry``),
-    whose method bodies are emitted verbatim from the spec text and
-    cannot carry dispatch calls.  Only methods defined on ``cls`` itself
-    are wrapped (inherited ones are already wrapped on the base class);
-    wrapping is idempotent.
+    parent->children index, memoized ``get_ancestor``).  Applied by
+    ``forks.install_engines`` on both ladders: the hand-written
+    ``ForkChoiceMixin`` (at definition time) and each markdown-compiled
+    class, whose method bodies are emitted verbatim from the spec text.
+    Only methods defined on ``cls`` itself are wrapped (inherited ones
+    are already wrapped on the base class); wrapping is idempotent.
 
     Write-side hooks (``on_block`` / ``update_latest_messages`` /
     ``on_attester_slashing``) feed the engine whenever it is attached,

@@ -11,24 +11,32 @@ from typing import Dict, Optional, Tuple
 _REGISTRY = {}
 
 
+def install_engines(cls) -> None:
+    """Wrap ``cls``'s own spec methods with every accelerator, from
+    outside, so the method bodies stay spec-shaped on both ladders.
+    Each install touches only methods defined on ``cls`` itself and is
+    idempotent; the order is fixed here once: the engine dispatches
+    first, the tracing spans last so they enclose the dispatch."""
+    from consensus_specs_tpu.das.engine import install_das_accel
+    from consensus_specs_tpu.forkchoice.proto_array import \
+        install_forkchoice_accel
+    from consensus_specs_tpu.obs import install_tracing
+    from consensus_specs_tpu.ops.att_prep import install_att_prep
+    from consensus_specs_tpu.ops.block_kernels import install_block_kernels
+    from consensus_specs_tpu.ops.committees import install_committee_shuffle
+    from consensus_specs_tpu.ops.epoch_kernels import install_vectorized_epoch
+    install_vectorized_epoch(cls)
+    install_att_prep(cls)
+    install_committee_shuffle(cls)
+    install_block_kernels(cls)
+    install_forkchoice_accel(cls)
+    install_das_accel(cls)
+    install_tracing(cls)
+
+
 def register_fork(name):
     def deco(cls):
-        # span-instrument the transition surface from outside so the
-        # method bodies stay spec-shaped (same pattern as the epoch /
-        # fork-choice engine installs; zero-overhead unless
-        # CS_TPU_PROFILE/CS_TPU_TRACE)
-        from consensus_specs_tpu.obs import install_tracing
-        from consensus_specs_tpu.ops.att_prep import install_att_prep
-        from consensus_specs_tpu.das.engine import install_das_accel
-        from consensus_specs_tpu.ops.block_kernels import \
-            install_block_kernels
-        from consensus_specs_tpu.ops.committees import \
-            install_committee_shuffle
-        install_att_prep(cls)
-        install_committee_shuffle(cls)
-        install_block_kernels(cls)
-        install_das_accel(cls)
-        install_tracing(cls)
+        install_engines(cls)
         _REGISTRY[name] = cls
         cls.fork = name
         return cls
@@ -100,29 +108,10 @@ def use_compiled_registry():
         main as _compile_all, _FORK_ORDER)
     _compile_all()
     importlib.invalidate_caches()  # compiled/ may have just been created
-    from consensus_specs_tpu.obs import install_tracing
-    from consensus_specs_tpu.ops.att_prep import install_att_prep
-    from consensus_specs_tpu.ops.block_kernels import install_block_kernels
-    from consensus_specs_tpu.ops.committees import install_committee_shuffle
-    from consensus_specs_tpu.ops.epoch_kernels import install_vectorized_epoch
-    from consensus_specs_tpu.forkchoice.proto_array import (
-        install_forkchoice_accel)
-    from consensus_specs_tpu.das.engine import install_das_accel
     for fork in _FORK_ORDER:
         mod = importlib.import_module(f"{__name__}.compiled.{fork}")
         importlib.reload(mod)
         cls = getattr(mod, f"Compiled{fork.capitalize()}Spec")
-        # compiled method bodies are emitted verbatim from the markdown,
-        # so the vectorized-epoch, attestation message-prep, committee
-        # shuffle, block-operation, proto-array fork-choice and DAS
-        # sampling dispatches (and the tracing spans) wrap them from
-        # outside
-        install_vectorized_epoch(cls)
-        install_att_prep(cls)
-        install_committee_shuffle(cls)
-        install_block_kernels(cls)
-        install_forkchoice_accel(cls)
-        install_das_accel(cls)
-        install_tracing(cls)
+        install_engines(cls)
         _REGISTRY[fork] = cls
     _spec_cache.clear()

@@ -17,7 +17,6 @@ from consensus_specs_tpu.utils.ssz import (
     Bitvector, Bitlist, Vector, List, Container,
 )  # noqa: F401 (compiled-spec namespace)
 from consensus_specs_tpu.utils import bls
-from consensus_specs_tpu.ops import epoch_kernels
 from . import register_fork
 from .phase0 import Phase0Spec
 from .light_client import LightClientMixin
@@ -440,8 +439,6 @@ class AltairSpec(SyncDutiesMixin, LightClientMixin, Phase0Spec):
             previous_target_balance, current_target_balance)
 
     def process_inactivity_updates(self, state):
-        if epoch_kernels.try_process_inactivity_updates(self, state):
-            return
         if self.get_current_epoch(state) == GENESIS_EPOCH:
             return
         participating = self.get_unslashed_participating_indices(
@@ -459,8 +456,6 @@ class AltairSpec(SyncDutiesMixin, LightClientMixin, Phase0Spec):
                     state.inactivity_scores[index])
 
     def process_rewards_and_penalties(self, state):
-        if epoch_kernels.try_process_rewards_and_penalties(self, state):
-            return
         if self.get_current_epoch(state) == GENESIS_EPOCH:
             return
         flag_deltas = [self.get_flag_index_deltas(state, flag_index)
@@ -474,8 +469,6 @@ class AltairSpec(SyncDutiesMixin, LightClientMixin, Phase0Spec):
                                       penalties[index])
 
     def process_slashings(self, state):
-        if epoch_kernels.try_process_slashings(self, state):
-            return
         epoch = self.get_current_epoch(state)
         total_balance = self.get_total_active_balance(state)
         adjusted_total_slashing_balance = min(

@@ -18,7 +18,6 @@ from consensus_specs_tpu.utils.ssz import (
     ByteList, ByteVector, Vector, List, Container,
 )  # noqa: F401 (compiled-spec namespace)
 from consensus_specs_tpu.utils import bls
-from consensus_specs_tpu.ops import epoch_kernels
 from . import register_fork
 from .altair import AltairSpec
 from .optimistic_sync import OptimisticSyncMixin
@@ -207,8 +206,6 @@ class BellatrixSpec(OptimisticSyncMixin, AltairSpec):
 
     def process_slashings(self, state):
         """beacon-chain.md:421 — PROPORTIONAL_SLASHING_MULTIPLIER_BELLATRIX."""
-        if epoch_kernels.try_process_slashings(self, state):
-            return
         epoch = self.get_current_epoch(state)
         total_balance = self.get_total_active_balance(state)
         adjusted_total_slashing_balance = min(

@@ -18,7 +18,6 @@ from consensus_specs_tpu.utils.ssz import (
 )  # noqa: F401 (compiled-spec namespace)
 from consensus_specs_tpu.utils import bls
 from consensus_specs_tpu.ops import kzg as _kzg
-from consensus_specs_tpu.ops import epoch_kernels
 from . import register_fork
 from .capella import CapellaSpec
 from .base_types import (
@@ -288,8 +287,6 @@ class DenebSpec(CapellaSpec):
     def process_registry_updates(self, state):
         """EIP-7514: activations capped by the activation churn limit
         (beacon-chain.md:438)."""
-        if epoch_kernels.try_process_registry_updates(self, state):
-            return
         for index, validator in enumerate(state.validators):
             if self.is_eligible_for_activation_queue(validator):
                 validator.activation_eligibility_epoch = Epoch(

@@ -20,9 +20,9 @@ Design differences from the reference (same observable behavior):
   view hashing).
 
 The class bodies below stay spec-shaped; the performance layer is
-installed from the outside (``install_forkchoice_accel`` at the bottom
-of this module, mirroring ``ops/epoch_kernels.install_vectorized_epoch``
-for the markdown-compiled ladder): ``get_head`` / ``get_weight`` /
+installed from the outside (``forks.install_engines`` at the bottom of
+this module, the same seam every fork class of both ladders goes
+through): ``get_head`` / ``get_weight`` /
 ``get_filtered_block_tree`` dispatch to the incremental proto-array
 engine (``forkchoice/proto_array.py``, ``CS_TPU_PROTO_ARRAY=0`` to
 disable), stores carry an incrementally-maintained parent->children
@@ -34,10 +34,9 @@ O(depth) per vote.
 from dataclasses import dataclass, field
 from typing import Dict, Set, Tuple
 
-from consensus_specs_tpu.forkchoice.proto_array import install_forkchoice_accel
-from consensus_specs_tpu.obs import install_tracing
 from consensus_specs_tpu.utils import bls
 from consensus_specs_tpu.utils.ssz import hash_tree_root
+from . import install_engines
 
 INTERVALS_PER_SLOT = 3
 
@@ -479,11 +478,8 @@ class ForkChoiceMixin:
             store.equivocating_indices.add(index)
 
 
-# proto-array dispatch + store bookkeeping, wrapped from the outside so
-# the method bodies above stay spec-shaped (the compiled ladder gets the
-# same treatment in ``forks.use_compiled_registry``)
-install_forkchoice_accel(ForkChoiceMixin)
-# span-instrument the handler surface on top of the accel dispatch
+# proto-array dispatch + store bookkeeping, then the handler spans,
+# wrapped from the outside so the method bodies above stay spec-shaped
 # (the fork classes only define the transition methods; on_block /
 # on_attestation / on_tick live here on the mixin)
-install_tracing(ForkChoiceMixin)
+install_engines(ForkChoiceMixin)

@@ -24,8 +24,6 @@ from consensus_specs_tpu.utils.ssz import (
 from consensus_specs_tpu.utils import bls
 from consensus_specs_tpu.utils.ssz.forest import hash_forest
 from consensus_specs_tpu.obs.tracing import span
-from consensus_specs_tpu.ops import epoch_kernels
-from consensus_specs_tpu.state import arrays as state_arrays
 from . import register_fork
 from .fork_choice import ForkChoiceMixin
 from .validator_guide import ValidatorGuideMixin
@@ -105,7 +103,6 @@ class Phase0Spec(ValidatorGuideMixin, ForkChoiceMixin):
         # (pysetup/spec_builders/phase0.py:59-105); unbounded dicts would grow
         # without limit across a long generator run.
         self._caches: Dict[str, "_LRUDict"] = {
-            "committee": _LRUDict(512, name="committee"),
             "proposer": _LRUDict(512, name="proposer"),
             "active_indices": _LRUDict(128, name="active_indices"),
             "total_balance": _LRUDict(128, name="total_balance"),
@@ -518,22 +515,15 @@ class Phase0Spec(ValidatorGuideMixin, ForkChoiceMixin):
         )))
 
     def get_beacon_committee(self, state, slot, index) -> Sequence[ValidatorIndex]:
-        """beacon-chain.md:1017; LRU-cached like pysetup/spec_builders/phase0.py:59-105"""
-        key = (hash_tree_root(state.validators), hash_tree_root(state.randao_mixes),
-               int(slot), int(index))
-        cached = self._caches["committee"].get(key)
-        if cached is None:
-            epoch = self.compute_epoch_at_slot(slot)
-            committees_per_slot = self.get_committee_count_per_slot(state, epoch)
-            cached = tuple(self.compute_committee(
-                indices=self.get_active_validator_indices(state, epoch),
-                seed=self.get_seed(state, epoch, DOMAIN_BEACON_ATTESTER),
-                index=(slot % self.SLOTS_PER_EPOCH) * committees_per_slot + index,
-                count=committees_per_slot * self.SLOTS_PER_EPOCH,
-            ))
-            self._caches["committee"][key] = cached
-        # immutable, uncopied: see get_active_validator_indices
-        return cached
+        """beacon-chain.md:1017"""
+        epoch = self.compute_epoch_at_slot(slot)
+        committees_per_slot = self.get_committee_count_per_slot(state, epoch)
+        return self.compute_committee(
+            indices=self.get_active_validator_indices(state, epoch),
+            seed=self.get_seed(state, epoch, DOMAIN_BEACON_ATTESTER),
+            index=(slot % self.SLOTS_PER_EPOCH) * committees_per_slot + index,
+            count=committees_per_slot * self.SLOTS_PER_EPOCH,
+        )
 
     def get_beacon_proposer_index(self, state) -> ValidatorIndex:
         key = (hash_tree_root(state.validators), hash_tree_root(state.randao_mixes),
@@ -711,7 +701,8 @@ class Phase0Spec(ValidatorGuideMixin, ForkChoiceMixin):
             signed_block.message, self.get_domain(state, DOMAIN_BEACON_PROPOSER))
         return bls.Verify(proposer.pubkey, signing_root, signed_block.signature)
 
-    # Epoch transitions run inside a StateArrays commit scope: the
+    # Epoch transitions run inside a StateArrays commit scope, opened
+    # around ``process_epoch`` by ``install_vectorized_epoch``: the
     # engine's balance-family column writes flush back to SSZ chunks
     # ONCE at scope exit instead of once per sub-transition.  Forks
     # whose epoch ordering interleaves non-engine balance writes between
@@ -725,11 +716,7 @@ class Phase0Spec(ValidatorGuideMixin, ForkChoiceMixin):
             self.process_slot(state)
             # Process epoch on the start slot of the next epoch
             if (state.slot + 1) % self.SLOTS_PER_EPOCH == 0:
-                if self._defer_epoch_commits:
-                    with state_arrays.commit_scope(state):
-                        self.process_epoch(state)
-                else:
-                    self.process_epoch(state)
+                self.process_epoch(state)
             state.slot = Slot(state.slot + 1)
 
     def process_slot(self, state) -> None:
@@ -947,8 +934,6 @@ class Phase0Spec(ValidatorGuideMixin, ForkChoiceMixin):
         return rewards, penalties
 
     def process_rewards_and_penalties(self, state) -> None:
-        if epoch_kernels.try_process_rewards_and_penalties(self, state):
-            return
         # No rewards are applied at the end of `GENESIS_EPOCH` because rewards
         # are for work done in the previous epoch
         if self.get_current_epoch(state) == GENESIS_EPOCH:
@@ -962,8 +947,6 @@ class Phase0Spec(ValidatorGuideMixin, ForkChoiceMixin):
 
     def process_registry_updates(self, state) -> None:
         """beacon-chain.md:1592"""
-        if epoch_kernels.try_process_registry_updates(self, state):
-            return
         # Process activation eligibility and ejections
         for index, validator in enumerate(state.validators):
             if self.is_eligible_for_activation_queue(validator):
@@ -986,8 +969,6 @@ class Phase0Spec(ValidatorGuideMixin, ForkChoiceMixin):
 
     def process_slashings(self, state) -> None:
         """beacon-chain.md:1619"""
-        if epoch_kernels.try_process_slashings(self, state):
-            return
         epoch = self.get_current_epoch(state)
         total_balance = self.get_total_active_balance(state)
         adjusted_total_slashing_balance = min(
@@ -1007,8 +988,6 @@ class Phase0Spec(ValidatorGuideMixin, ForkChoiceMixin):
             state.eth1_data_votes = type(state.eth1_data_votes)()
 
     def process_effective_balance_updates(self, state) -> None:
-        if epoch_kernels.try_process_effective_balance_updates(self, state):
-            return
         for index, validator in enumerate(state.validators):
             balance = state.balances[index]
             HYSTERESIS_INCREMENT = uint64(

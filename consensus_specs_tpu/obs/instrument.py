@@ -2,12 +2,9 @@
 
 The fork ladders keep their method bodies spec-shaped (hand-written
 classes mirror the markdown; compiled classes ARE the markdown), so
-tracing wraps them from outside — the same installation pattern as
-``ops/epoch_kernels.install_vectorized_epoch`` and
-``forkchoice/proto_array.install_forkchoice_accel``:
-``forks.register_fork`` applies :func:`install_tracing` to every
-hand-written fork class at definition time, and
-``forks.use_compiled_registry`` applies it to each compiled class.
+tracing wraps them from outside, last of the installs
+``forks.install_engines`` applies to every fork class of both ladders
+(and to ``ForkChoiceMixin``), so a span encloses the engine dispatch.
 
 Only methods defined on the class itself are wrapped (an inherited
 method is already wrapped on the base; a fork's override gets its own

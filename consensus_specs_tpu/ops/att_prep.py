@@ -19,10 +19,10 @@ spec bodies will find them: the exact container roots are poked into
 the SSZ root memos (value-semantics copies inherit them, so the
 ``PendingAttestation`` path is also warm), and the signing roots go
 into a per-block lookup consulted by an externally-installed
-``is_valid_indexed_attestation`` wrapper (``install_att_prep`` — same
-outside-in pattern as the epoch / fork-choice engine installs, so spec
-method bodies stay spec-shaped and the markdown-compiled ladder gets
-the identical treatment).  Every prepared verification then feeds the
+``is_valid_indexed_attestation`` wrapper (``install_att_prep``, applied
+to both ladders by ``forks.install_engines``, so spec method bodies stay
+spec-shaped and the markdown-compiled ladder gets the identical
+treatment).  Every prepared verification then feeds the
 existing deferred-batch RLC flush unchanged.
 
 The lookup key includes the fork version in force at the attestation's
@@ -214,8 +214,7 @@ def install_att_prep(cls) -> None:
     ``is_valid_indexed_attestation`` (serve the prepared signing root;
     fall through to the spec body on any miss).  Only methods defined
     on ``cls`` itself are wrapped; wrapping is idempotent.  Applied to
-    the hand-written ladder by ``forks.register_fork`` and to each
-    markdown-compiled class by ``forks.use_compiled_registry``."""
+    every fork class of both ladders by ``forks.install_engines``."""
     fn = cls.__dict__.get("process_operations")
     if fn is not None and not getattr(fn, "_att_prep_wrapper", False):
         @functools.wraps(fn)

@@ -16,9 +16,10 @@ Both answers are constant where the spec recomputes them:
 * ``process_sync_aggregate``: the first registry index of each pubkey,
   cached under ``_caches["pubkey_index"]`` keyed on the registry root.
 
-:func:`install_block_kernels` installs both from outside, like
-``att_prep`` and the epoch engine, so the hand-written and the
-markdown-compiled ladders run them; the spec bodies stay as written.
+:func:`install_block_kernels` installs both from outside, through
+``forks.install_engines`` like ``att_prep`` and the epoch engine, so the
+hand-written and the markdown-compiled ladders run them; the spec bodies
+stay as written.
 """
 import functools
 
@@ -112,8 +113,8 @@ def process_sync_aggregate(spec, state, sync_aggregate):
 def install_block_kernels(cls) -> None:
     """Install the ``get_base_reward_per_increment`` memo and this
     module's ``process_sync_aggregate`` on ``cls``'s own methods, once.
-    Applied to the hand-written ladder by ``forks.register_fork`` and to
-    each markdown-compiled class by ``forks.use_compiled_registry``."""
+    Applied to every fork class of both ladders by
+    ``forks.install_engines``."""
     fn = cls.__dict__.get("get_base_reward_per_increment")
     if fn is not None and not getattr(fn, "_block_kernel", False):
         setattr(cls, "get_base_reward_per_increment",

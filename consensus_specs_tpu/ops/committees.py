@@ -12,8 +12,9 @@ computed once and shared by all the positions they cover.  It returns
 exactly ``[compute_shuffled_index(i, n, seed) for i in range(n)]``.
 
 :func:`install_committee_shuffle` replaces ``get_beacon_committee`` from
-outside (same pattern as ``att_prep`` and the epoch engine), so both the
-hand-written and the markdown-compiled ladders slice the same list.
+outside (through ``forks.install_engines``, like ``att_prep`` and the
+epoch engine), so both the hand-written and the markdown-compiled ladders
+slice the same list.
 The shuffle is cached on the spec instance under ``_caches["shuffling"]``,
 keyed on what ``compute_committee`` reads: the attester seed
 (``get_seed(state, epoch, DOMAIN_BEACON_ATTESTER)``), the epoch, and the
@@ -131,9 +132,8 @@ def beacon_committee(spec, state, slot, index):
 def install_committee_shuffle(cls) -> None:
     """Replace ``cls``'s own ``get_beacon_committee`` with
     :func:`beacon_committee`.  Only a method defined on ``cls`` itself is
-    replaced, once.  Applied to the hand-written ladder by
-    ``forks.register_fork`` and to each markdown-compiled class by
-    ``forks.use_compiled_registry``."""
+    replaced, once.  Applied to every fork class of both ladders by
+    ``forks.install_engines``."""
     fn = cls.__dict__.get("get_beacon_committee")
     if fn is None or getattr(fn, "_committee_shuffle", False):
         return

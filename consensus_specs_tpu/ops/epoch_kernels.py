@@ -11,21 +11,21 @@ re-expresses them as columnar array kernels over the canonical
 struct-of-arrays state store (``state/arrays.py``): columns are
 extracted once per state lineage, revalidated structurally against the
 SSZ mutation generations, mutated copy-on-write by the kernels, and —
-inside the ``state_arrays.commit_scope`` the fork ladder opens around
-``process_epoch`` — committed back to SSZ chunks once per epoch
-transition instead of once per sub-transition.
+inside the ``state_arrays.commit_scope`` that
+:func:`install_vectorized_epoch` opens around ``process_epoch`` —
+committed back to SSZ chunks once per epoch transition instead of once
+per sub-transition.
 
 Layering mirrors the BLS backend switch (``utils/bls.py``):
 
   use_vectorized() / use_loops() / use_auto()   runtime switch; auto
       (the default) is ON unless ``CS_TPU_VECTORIZED_EPOCH=0``
-  try_process_*(spec, state) -> bool            entry points the fork
-      ladder calls first; True means the vectorized engine committed the
-      transition, False means "run the spec loop" (switch off, genesis
-      no-op, or a guard tripped)
-  install_vectorized_epoch(cls)                 wraps a markdown-compiled
-      spec class's epoch methods with the same dispatch (the compiled
-      ladder cannot carry hand-written calls in its method bodies)
+  try_process_*(spec, state) -> bool            per-method dispatch; True
+      means the vectorized engine committed the transition, False means
+      "run the spec body" (switch off, genesis no-op, or a guard tripped)
+  install_vectorized_epoch(cls)                 wraps a spec class's own
+      epoch methods with that dispatch from outside, on both ladders
+      (``forks.install_engines``), so the method bodies stay spec-shaped
 
 Exactness contract: every kernel reproduces the spec loops bit-for-bit
 — same uint64 truncations, same clamp-at-zero balance decreases, same
@@ -148,7 +148,7 @@ def _guard(*products) -> None:
 # once per state lineage, revalidated against the SSZ sequences'
 # mutation generations (a write through the sequence API bumps the
 # generation, so a stale column is structurally impossible), and — when
-# the fork ladder's ``commit_scope`` is open around ``process_epoch`` —
+# the ``commit_scope`` installed around ``process_epoch`` is open —
 # committed back to SSZ chunks once per transition.  The root-keyed
 # ``_COLS_CACHE`` LRU this module used to keep is gone; every guard
 # fallback flushes pending column writes first so the spec loop always
@@ -330,7 +330,7 @@ def _audited(spec, state, site, method_name, fast_fn) -> bool:
     except (_Fallback, faults.InjectedFault,
             supervisor.DeadlineExceeded) as exc:
         faults.count_fallback(_EPOCH_FALLBACKS, exc, site=site)
-    # the spec loop, via the wrapped/inline-dispatched spec method:
+    # the spec loop, via the wrapped spec method:
     # probing() makes every try_process_* decline — which books the
     # path=loop counter and flushes pending columns itself (_decline),
     # so this helper must NOT double-book either — and the replay
@@ -931,7 +931,7 @@ def _effective_balance_updates(spec, state) -> None:
 
 
 # ---------------------------------------------------------------------------
-# Compiled-ladder routing
+# Spec-class routing
 # ---------------------------------------------------------------------------
 
 _TRY_BY_NAME = {
@@ -945,9 +945,9 @@ _TRY_BY_NAME = {
 
 def install_vectorized_epoch(cls) -> None:
     """Wrap a spec class's own epoch methods with the vectorized
-    dispatch.  Used for the markdown-compiled ladder, whose method bodies
-    are emitted verbatim from the spec text and therefore cannot carry
-    the hand-written ladder's inline ``try_process_*`` calls.  Only
+    dispatch.  Applied to every fork class of both ladders by
+    ``forks.install_engines``, so neither the hand-written bodies nor the
+    ones emitted verbatim from the markdown carry a dispatch call.  Only
     methods defined on ``cls`` itself are wrapped (inherited ones are
     already wrapped on the base class), and wrapping is idempotent.
 

@@ -28,8 +28,8 @@ state object itself:
   pays for exactly that column (``registry_writable``), counted in
   ``state_arrays.cow_copies``.
 * **One commit per epoch transition.**  Inside a
-  :func:`commit_scope` (opened around ``process_epoch`` by the fork
-  ladder), engine writes to the balances / inactivity-score columns
+  :func:`commit_scope` (opened around ``process_epoch`` by
+  ``epoch_kernels.install_vectorized_epoch``), engine writes to the balances / inactivity-score columns
   stay in the store and flush back to SSZ chunks once, at scope exit,
   through the chunk-packed ``replace_basic_items(packed=)`` fast path —
   instead of once per sub-transition.  Registry (validator) columns

@@ -21,6 +21,13 @@ fail-loud generation checks — into a machine-checked *static proof*:
    scope root are the classes ``fork``-commits-early and
    ``CheckpointRefused`` only catch dynamically.
 
+   The epoch bodies carry no dispatch call of their own:
+   ``install_vectorized_epoch`` wraps each fork class's definition so
+   the ``try_process_*`` dispatch (which flushes the store on a
+   decline) runs before the body.  The analysis models that wrapper
+   from its ``_TRY_BY_NAME`` table: a ``register_fork`` class's method
+   named there is entered through the table's dispatch function.
+
    Classes that opt out of deferred commits
    (``_defer_epoch_commits = False``, e.g. custody_game) are excluded
    from the scope closure — their epoch bodies never run under an open
@@ -87,6 +94,12 @@ DEFERRABLE_FIELDS = ("balances", "inactivity_scores")
 
 OPT_OUT_ATTR = "_defer_epoch_commits"
 
+# install_vectorized_epoch's method -> try_process_* dispatch table,
+# and the decorator whose classes the installs wrap
+EPOCH_KERNELS_REL = "consensus_specs_tpu/ops/epoch_kernels.py"
+DISPATCH_TABLE = "_TRY_BY_NAME"
+INSTALLING_DECORATOR = "register_fork"
+
 
 def _tail(call):
     f = call.func
@@ -138,6 +151,7 @@ class CommitScopeAnalysis:
             for base in self.graph.mro(name):
                 self._subclasses.setdefault(base.name, set()).add(name)
         self.opted_out = self._opted_out_classes()
+        self._dispatch = self._dispatch_entries()
         # the analyzed universe: everything on the graph except code
         # that can only run on an opted-out class, the enforcement
         # layers themselves, and the AUTO-COMPILED ladder — its bodies
@@ -170,6 +184,35 @@ class CommitScopeAnalysis:
                     if val is False:
                         out.add(name)
                     break
+        return out
+
+    def _dispatch_entries(self):
+        """``(class name, method name) -> try_process_*`` for every
+        method ``install_vectorized_epoch`` wraps: the keys of its
+        ``_TRY_BY_NAME`` table, defined on a ``register_fork`` class."""
+        mod = self.graph.modules.get(EPOCH_KERNELS_REL)
+        if mod is None:
+            return {}
+        table = {}
+        for node in mod.tree.body:
+            if isinstance(node, ast.Assign) and len(node.targets) == 1 \
+                    and isinstance(node.targets[0], ast.Name) \
+                    and node.targets[0].id == DISPATCH_TABLE \
+                    and isinstance(node.value, ast.Dict):
+                for key, val in zip(node.value.keys, node.value.values):
+                    if isinstance(key, ast.Constant) \
+                            and isinstance(val, ast.Name) \
+                            and val.id in mod.funcs:
+                        table[key.value] = mod.funcs[val.id]
+        out = {}
+        for cls in self.graph.classes.values():
+            if not any(_tail(d) == INSTALLING_DECORATOR
+                       for d in cls.node.decorator_list
+                       if isinstance(d, ast.Call)):
+                continue
+            for meth in cls.methods:
+                if meth in table:
+                    out[(cls.name, meth)] = table[meth]
         return out
 
     # -- resolution (virtual dispatch) --------------------------------------
@@ -236,6 +279,10 @@ class CommitScopeAnalysis:
 
     def _extract(self, fn):
         ev = _FnEvents()
+        entry = self._dispatch.get((fn.cls_name, fn.name))
+        if entry is not None and entry in self._fn_set:
+            # the installed wrapper's dispatch runs before the body
+            ev.calls.append(((fn.node.lineno, -1), frozenset({entry})))
         self._extract_into(fn, ast.walk(fn.node), ev)
         return ev
 
@@ -368,8 +415,8 @@ class CommitScopeAnalysis:
         return not targets or any(t.rel == ARRAYS_REL for t in targets)
 
     def _wrap_orig_calls(self, body_nodes, ev):
-        """``install_vectorized_epoch`` wraps compiled ``process_epoch``
-        bodies through a ``_orig(self, state)`` cell — statically
+        """``install_vectorized_epoch`` wraps every ``process_epoch``
+        body through a ``_orig(self, state)`` cell — statically
         unresolvable, so the scope body unions every non-opted-out
         ``process_epoch`` definition (exactly what the wrapper wraps)."""
         for node in body_nodes:

@@ -58,7 +58,7 @@ from .. import effects
 
 NAME = "effects"
 CODE_PREFIXES = ("E12",)
-VERSION = 1
+VERSION = 2
 GRANULARITY = "tree"
 # dependency-granular cache inputs: everything the analysis reads is
 # the project graph's source universe (tools/ excluded exactly as the

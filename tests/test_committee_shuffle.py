@@ -9,7 +9,7 @@ import sys
 import pytest
 
 from consensus_specs_tpu.config import load_config, load_preset
-from consensus_specs_tpu.forks import build_spec
+from consensus_specs_tpu.forks import build_spec, install_engines
 from consensus_specs_tpu.ops import committees
 from consensus_specs_tpu.test_infra.metrics import counting
 
@@ -110,7 +110,7 @@ def test_committees_match_per_index_compiled_ladder():
     from consensus_specs_tpu.forks.compiled.deneb import CompiledDenebSpec
     for klass in CompiledDenebSpec.__mro__:
         if isinstance(klass.__dict__.get("fork"), str):
-            committees.install_committee_shuffle(klass)
+            install_engines(klass)
     assert CompiledPhase0Spec.get_beacon_committee._committee_shuffle
     spec = CompiledDenebSpec(load_preset("minimal"), load_config("minimal"),
                              preset_name="minimal")

@@ -21,6 +21,7 @@ from random import Random
 import numpy as np
 import pytest
 
+from consensus_specs_tpu import faults
 from consensus_specs_tpu.forks import build_spec
 from consensus_specs_tpu.ops import epoch_kernels as ek
 from consensus_specs_tpu.state import arrays as state_arrays
@@ -404,6 +405,30 @@ def test_kernels_jit_under_jax():
         jax.config.update("jax_enable_x64", prev_x64)
 
 
+def test_declining_guard_runs_each_hand_body_once():
+    """The hand-written altair epoch bodies carry no dispatch of their
+    own: with every site declining (an injected fault at each),
+    ``process_epoch`` dispatches each sub-transition once and runs its
+    spec body once — one counted fallback per site, and exactly the
+    forced-loop post-state."""
+    spec, state = _altair_state("altair", seed=31)
+    s_loop, s_vec = state.copy(), state.copy()
+    ek.use_loops()
+    spec.process_epoch(s_loop)
+    ek.use_vectorized()
+    sites = [site for site in faults.SITES if site.startswith("epoch.")]
+    assert len(sites) == len(ALTAIR_VECTORIZED_FNS)
+    sched = faults.FaultSchedule(triggers={site: {1} for site in sites})
+    with counting() as delta, faults.injected(sched):
+        spec.process_epoch(s_vec)
+    assert sched.calls == {site: 1 for site in sites}
+    assert sched.fully_fired()
+    assert delta["epoch.fallbacks{reason=injected}"] == len(sites)
+    assert delta["epoch.transition{path=loop}"] == len(sites)
+    assert delta["epoch.transition{path=vectorized}"] == 0
+    assert hash_tree_root(s_loop) == hash_tree_root(s_vec)
+
+
 def test_install_vectorized_epoch_idempotent():
     calls = []
 
@@ -426,9 +451,10 @@ def test_install_vectorized_epoch_idempotent():
 
 def test_compiled_ladder_vectorized_differential():
     """``install_vectorized_epoch`` routes the engine into the markdown-
-    compiled ladder (``use_compiled_registry`` wiring): the wrapped
-    compiled altair spec must commit the same full-epoch post-state
-    through the array engine as through its verbatim-emitted loops."""
+    compiled ladder (through ``forks.install_engines``, the wiring
+    ``use_compiled_registry`` uses): the wrapped compiled altair spec
+    must commit the same full-epoch post-state through the array engine
+    as through its verbatim-emitted loops."""
     import os
     import subprocess
     import sys
@@ -436,12 +462,13 @@ def test_compiled_ladder_vectorized_differential():
     subprocess.run([sys.executable, "-m", "consensus_specs_tpu.compiler"],
                    check=True, cwd=repo, capture_output=True)
     from consensus_specs_tpu.config import load_config, load_preset
+    from consensus_specs_tpu.forks import install_engines
     from consensus_specs_tpu.forks.compiled.altair import CompiledAltairSpec
     # wrap the whole lineage: process_effective_balance_updates lives on
     # the compiled phase0 base, not on the altair class itself
     for klass in CompiledAltairSpec.__mro__:
         if isinstance(klass.__dict__.get("fork"), str):
-            ek.install_vectorized_epoch(klass)
+            install_engines(klass)
     spec = CompiledAltairSpec(load_preset("minimal"), load_config("minimal"),
                               preset_name="minimal")
     state = _genesis(spec)

@@ -14,6 +14,8 @@ import json
 import os
 import sys
 
+import pytest
+
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from consensus_specs_tpu.tools.speclint import (
@@ -1222,6 +1224,50 @@ def test_e1201_opted_out_class_excluded(tmp_path):
         "    def process_epoch(self, state):\n"
         "        state.balances[0] += 2\n")
     assert effects_pass.check_tree(_fx_tree(tmp_path, engine=engine)) == []
+
+
+_FX_INSTALLED_KERNELS = (
+    "from consensus_specs_tpu.state import arrays as state_arrays\n"
+    "def try_fast(spec, state):\n"
+    "    state_arrays.flush(state)\n"
+    "    return False\n"
+    "_TRY_BY_NAME = {'process_rewards': try_fast}\n")
+
+_FX_INSTALLED_ENGINE = (
+    "from consensus_specs_tpu.state import arrays as state_arrays\n"
+    "from consensus_specs_tpu.forks import register_fork\n"
+    "@register_fork('demo')\n"
+    "class DemoSpec:\n"
+    "    def process_slots(self, state):\n"
+    "        with state_arrays.commit_scope(state):\n"
+    "            self.process_epoch(state)\n"
+    "    def process_epoch(self, state):\n"
+    "        self.process_rewards(state)\n"
+    "    def process_rewards(self, state):\n"
+    "        self.apply(state)\n"
+    "    def apply(self, state):\n"
+    "        state.balances[0] += 1\n")
+
+
+@pytest.mark.parametrize("case,codes", [
+    # the install wrapper's dispatch flushes before the spec-shaped body
+    ("installed", []),
+    # no register_fork: nothing wraps the body, its write escapes
+    ("not_registered", ["E1201"]),
+    # a dispatch that declines without flushing guards nothing
+    ("dispatch_without_flush", ["E1201"]),
+])
+def test_e1201_install_wrapper_guards_spec_shaped_body(tmp_path, case,
+                                                       codes):
+    engine, kernels = _FX_INSTALLED_ENGINE, _FX_INSTALLED_KERNELS
+    if case == "not_registered":
+        engine = engine.replace("@register_fork('demo')\n", "")
+    elif case == "dispatch_without_flush":
+        kernels = kernels.replace("    state_arrays.flush(state)\n", "")
+    root = _fx_tree(tmp_path, engine=engine, extra=[
+        ("consensus_specs_tpu/ops/epoch_kernels.py", kernels)])
+    findings = effects_pass.check_tree(root)
+    assert [f.code for f in findings] == codes
 
 
 def test_e1202_fork_state_in_scope(tmp_path):
