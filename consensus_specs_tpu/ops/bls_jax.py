@@ -22,6 +22,7 @@ degenerate pairs that contribute the identity to the pairing product.
 """
 import threading
 from collections import OrderedDict
+from typing import NamedTuple
 
 import numpy as np
 import jax  # tree_util; array ops ride the kernel backend switch
@@ -548,7 +549,9 @@ def aggregate_verify_batch(items) -> list:
 # so the device work is: one batched pubkey aggregation, one batched
 # 128-bit G1 scaling, one G2 MSM over the signatures, hash-to-curve, and
 # a SINGLE product pairing check (one final exponentiation) - versus one
-# full 2-pair pairing check per lane on the per-lane path.
+# full 2-pair pairing check per lane on the per-lane path.  The pubkey
+# aggregation and scaling, which read no signature, are their own
+# phase, launched before the host decodes the signatures.
 # ---------------------------------------------------------------------------
 
 @kjit
@@ -587,66 +590,103 @@ _PK_ROWS_DISPATCHED = registry.counter("bls.rlc.pk_rows").labels(
     kind="dispatched")
 
 
-def rlc_combined_check(pk_slots, msgs, sig_pts, scalars, extra_pairs=(),
-                       mesh_devices=None) -> bool:
-    """One product pairing for a whole flushed batch.
+class RlcPubkeySide(NamedTuple):
+    """The in-flight device arrays of an RLC flush's pubkey phase
+    (:func:`rlc_pubkey_phase`)."""
+    n: int              # items
+    bucket: int         # lanes the programs run
+    bits: object        # (bucket, 128) MSB-first scalar bit planes
+    aggp: tuple         # normalized [r_i] agg_pk_i, (bucket,)
+    agg_inf: object     # their identity flags
+
+
+def rlc_pubkey_phase(pk_slots, scalars):
+    """Launch the part of an RLC flush that reads neither signature nor
+    message: the pubkey tree sum, the 128-bit G1 scaling and its
+    normalization, a few programs with most of the flush's device time.
+    Returns the in-flight device arrays (an :class:`RlcPubkeySide`;
+    None for no items) without waiting on the device, so the host
+    decodes the signatures while they run.  Hash-to-curve stays out:
+    launched here, its dozens of small programs queue behind the tree
+    sum and hold the host until it is done (TPU v5e), which cancels
+    the overlap.
 
     ``pk_slots``: per item, its pubkeys' slots in the pubkey table
-    (``_slot_rows``: already KeyValidate-checked); ``msgs``: per-item message bytes;
-    ``sig_pts``: per-item oracle G2Points (subgroup-checked; infinity
-    allowed); ``scalars``: the per-item 128-bit RLC coefficients;
-    ``extra_pairs``: pre-scaled oracle ``(G1Point, G2Point)`` pairs
-    appended to the product (deferred raw pairing checks, e.g. the
-    blob-KZG batch).  ``mesh_devices``: optional 1D device tuple - the
-    signature MSM shards its point axis across it through
-    ``parallel.sharded_verify.make_sharded_g2_msm``.
+    (``_slot_rows``: already KeyValidate-checked); ``scalars``: the
+    per-item 128-bit RLC coefficients.
     """
     n = len(pk_slots)
-    assert n == len(msgs) == len(sig_pts) and len(scalars) >= n
+    assert len(scalars) >= n
+    if not n:
+        return None
+    bucket = PR.lane_bucket(n)
+    npk_pad = max(_N_MIN, _pow2(max(len(r) for r in pk_slots)))
+    pad_scalars = list(scalars[:n]) + [0] * (bucket - n)
+    _PK_ROWS_LIVE.add(sum(len(r) for r in pk_slots))
+    _PK_ROWS_DISPATCHED.add(bucket * npk_pad)
+
+    with span("bls.stage.host_pack"):
+        pk_pts = tuple(jnp.asarray(a) for a in _g1_cache.grid(
+            pk_slots, bucket, npk_pad))
+        bits = jnp.asarray(_bits_msb(pad_scalars, RLC_SCALAR_BITS))
+    with span("bls.stage.msm"):
+        # per-item aggregate, then the 128-bit scale
+        agg = _j_tree_sum(pk_pts)
+        aggp, agg_inf = _j_g1_normalize_flag(_j_g1_scale(agg, bits))
+    return RlcPubkeySide(n, bucket, bits, aggp, agg_inf)
+
+
+def rlc_signature_phase(pk_side, msgs, sig_pts, extra_pairs=(),
+                        mesh_devices=None) -> bool:
+    """Finish an RLC flush: the G2 MSM over the signatures,
+    hash-to-curve of the messages, then the ONE product pairing over the
+    item pairs, the folded signature pair and ``extra_pairs``.
+
+    ``pk_side``: :func:`rlc_pubkey_phase`'s result; ``msgs``: per-item
+    message bytes; ``sig_pts``: per-item oracle G2Points
+    (subgroup-checked; infinity allowed); ``extra_pairs``: pre-scaled
+    oracle ``(G1Point, G2Point)`` pairs appended to the product
+    (deferred raw pairing checks, e.g. the blob-KZG batch).
+    ``mesh_devices``: optional 1D device tuple - the signature MSM
+    shards its point axis across it through
+    ``parallel.sharded_verify.make_sharded_g2_msm``.
+    """
+    n = pk_side.n if pk_side else 0
+    assert n == len(msgs) == len(sig_pts)
     px_parts, py_parts = [], []
     qx0_parts, qx1_parts, qy0_parts, qy1_parts = [], [], [], []
     degen_parts = []
     if n:
-        bucket = PR.lane_bucket(n)
-        npk_pad = max(_N_MIN, _pow2(max(len(r) for r in pk_slots)))
-        pad_scalars = list(scalars[:n]) + [0] * (bucket - n)
-        _PK_ROWS_LIVE.add(sum(len(r) for r in pk_slots))
-        _PK_ROWS_DISPATCHED.add(bucket * npk_pad)
-
+        bucket = pk_side.bucket
         with span("bls.stage.host_pack"):
-            pk_pts = tuple(jnp.asarray(a) for a in _g1_cache.grid(
-                pk_slots, bucket, npk_pad))
             sig_packed = PT.g2_pack(list(sig_pts)
                                     + [G2Point.inf()] * (bucket - n))
-            bits = jnp.asarray(_bits_msb(pad_scalars, RLC_SCALAR_BITS))
-
         with span("bls.stage.msm"):
-            # pubkey side: per-item aggregate, then the 128-bit scale
-            agg = _j_tree_sum(pk_pts)
-            aggp, agg_inf = _j_g1_normalize_flag(_j_g1_scale(agg, bits))
-            # signature side: the G2 MSM (points-sharded when a mesh is
-            # registered; uneven batches pad with identity lanes, so
-            # ANY bucket size shards across ANY device count)
+            # the G2 MSM (points-sharded when a mesh is registered;
+            # uneven batches pad with identity lanes, so ANY bucket
+            # size shards across ANY device count)
             if mesh_devices:
                 from consensus_specs_tpu.parallel import sharded_verify
                 s_total = sharded_verify.sharded_g2_msm_padded(
-                    sig_packed, bits, tuple(mesh_devices))
+                    sig_packed, pk_side.bits, tuple(mesh_devices))
             else:
-                s_total = _j_g2_scale_sum(sig_packed, bits)
+                s_total = _j_g2_scale_sum(sig_packed, pk_side.bits)
             s_total = jax.tree_util.tree_map(lambda a: a[None], s_total)
             s_aff = _program_g2_normalize(s_total)
             s_inf = jnp.asarray(PT.g2_is_identity(s_aff))
-            _profile_sync(aggp)
 
         with span("bls.stage.hash_to_field"):
             u0, u1 = HTC.hash_to_field_host(
                 list(msgs) + [b""] * (bucket - n))
         with span("bls.stage.htc"):
             hpt = _program_htc(u0, u1)
-            _profile_sync(hpt)
+            # a traced flush's one drain: here, after the signatures
+            # were decoded, so tracing keeps the pubkey side's overlap
+            _profile_sync((pk_side.aggp, s_aff, hpt))
 
     with span("bls.stage.assemble"):
         if n:
+            aggp = pk_side.aggp
             # flat pairs axis: n item pairs + the folded signature pair
             px_parts += [aggp[0][:n], jnp.asarray(_NEG_G1[0])]
             py_parts += [aggp[1][:n], jnp.asarray(_NEG_G1[1])]
@@ -654,7 +694,8 @@ def rlc_combined_check(pk_slots, msgs, sig_pts, scalars, extra_pairs=(),
             qx1_parts += [hpt[0][1][:n], s_aff[0][1]]
             qy0_parts += [hpt[1][0][:n], s_aff[1][0]]
             qy1_parts += [hpt[1][1][:n], s_aff[1][1]]
-            degen_parts += [np.asarray(agg_inf)[:n], np.asarray(s_inf)]
+            degen_parts += [np.asarray(pk_side.agg_inf)[:n],
+                            np.asarray(s_inf)]
         if extra_pairs:
             eg1 = PT.g1_pack([p for p, _ in extra_pairs])
             eg2 = PT.g2_pack([q for _, q in extra_pairs])
@@ -675,6 +716,17 @@ def rlc_combined_check(pk_slots, msgs, sig_pts, scalars, extra_pairs=(),
     with span("bls.stage.pairing"):
         return bool(np.asarray(
             PR.staged_product_pairing_check(px, py, q, degen)))
+
+
+def rlc_combined_check(pk_slots, msgs, sig_pts, scalars, extra_pairs=(),
+                       mesh_devices=None) -> bool:
+    """One product pairing for a whole flushed batch:
+    :func:`rlc_pubkey_phase` then :func:`rlc_signature_phase` (see them
+    for the arguments).  ``bls_rlc`` calls the phases apart, decoding
+    the signatures between them."""
+    return rlc_signature_phase(rlc_pubkey_phase(pk_slots, scalars), msgs,
+                               sig_pts, extra_pairs, mesh_devices)
+
 
 # Public staged-program surface (the sharded step in
 # consensus_specs_tpu.parallel and the dryrun's numpy cross-check both

@@ -34,7 +34,8 @@ per-lane path to bisect and report exactly which item failed.
 
 Backends: the python oracle, the native C library (streaming product
 pairing in ``csrc/bls12_381.c``), and the JAX device path
-(``ops/bls_jax.rlc_combined_check``), which lowers the signature MSM
+(``ops/bls_jax.rlc_pubkey_phase``, launched before the signatures are
+decoded, then ``rlc_signature_phase``), which lowers the signature MSM
 onto the points-sharded mesh program of
 ``parallel/sharded_verify.make_sharded_g2_msm`` when a mesh has been
 registered via :func:`use_mesh`.
@@ -48,6 +49,7 @@ from consensus_specs_tpu.ops.bls12_381.fields import R_ORDER
 from consensus_specs_tpu.ops.bls12_381.hash_to_curve import hash_to_g2, DST_G2
 from consensus_specs_tpu.ops.bls12_381.pairing import multi_pairing_check
 from consensus_specs_tpu import supervisor
+from consensus_specs_tpu.obs import registry
 from consensus_specs_tpu.obs.tracing import span
 
 SCALAR_BITS = 128
@@ -220,6 +222,13 @@ def _check_native(items, extra_checks, scalars):
     return nb.pairing_check_compressed(g1s, g2s)
 
 
+# jax-backend flushes whose pubkey phase launched before the
+# signatures were decoded: ``used``, or ``discarded`` by an invalid one
+_EARLY_DISPATCH = registry.counter("bls.rlc.early_dispatch")
+_EARLY_USED = _EARLY_DISPATCH.labels(outcome="used")
+_EARLY_DISCARDED = _EARLY_DISPATCH.labels(outcome="discarded")
+
+
 def _check_jax(items, extra_checks, scalars):
     from consensus_specs_tpu.ops import bls_jax
     n = len(items)
@@ -229,23 +238,28 @@ def _check_jax(items, extra_checks, scalars):
         pk_slots = bls_jax._slot_rows([pubkeys for pubkeys, _, _ in items])
         if None in pk_slots:
             return None
+    # the pubkey side reads no signature: its device programs run while
+    # the host decodes the signatures below
+    pk_side = bls_jax.rlc_pubkey_phase(pk_slots, scalars[:n])
     sig_pts = []
     with span("bls.stage.sig_decompress"):
         for _, _, signature in items:
             spt = bls_jax._decompress_g2(signature)
             if spt is None:
+                _EARLY_DISCARDED.add()
                 return None
             sig_pts.append(spt)
-    msgs = [bytes(message) for _, message, _ in items]
+    if pk_side is not None:
+        _EARLY_USED.add()
     extra_pairs = []
     for (chk_pairs, _label), r in zip(extra_checks, scalars[n:]):
         for p, q in chk_pairs:
             extra_pairs.append((_scale_g1_host(p, r), q))
     if not pk_slots and not extra_pairs:
         return True
-    return bls_jax.rlc_combined_check(
-        pk_slots, msgs, sig_pts, scalars[:n], extra_pairs=extra_pairs,
-        mesh_devices=mesh_devices())
+    return bls_jax.rlc_signature_phase(
+        pk_side, [bytes(message) for _, message, _ in items], sig_pts,
+        extra_pairs=extra_pairs, mesh_devices=mesh_devices())
 
 
 _COMBINERS = {"py": _check_py, "native": _check_native, "jax": _check_jax}

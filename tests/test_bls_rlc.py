@@ -421,23 +421,107 @@ def test_rlc_pk_rows_count_live_and_dispatched_rows():
     assert b"PK-ROWS 5 24" in proc.stdout, proc.stdout.decode()[-500:]
 
 
+_EARLY = registry.counter("bls.rlc.early_dispatch")
+
+
 @pytest.mark.parametrize("kind", ["pubkey", "signature"])
 @pytest.mark.parametrize("position", [0, 1, 2])
 def test_check_jax_invalid_item_is_structural(kind, position, monkeypatch):
     """The device combiner answers None (bisect) for an invalid pubkey
-    or signature wherever it sits, before any device work."""
+    or signature wherever it sits.  An invalid pubkey stops it before
+    any device work; an invalid signature after the pubkey side was
+    launched, which it discards, and before the signature MSM and the
+    pairing."""
     from consensus_specs_tpu.ops import bls_jax
+    from consensus_specs_tpu.ops.jax_bls import pairing as PR
 
     def no_device(*_args, **_kw):
         raise AssertionError("an invalid batch reached the device")
+    launched = []
+
+    def pubkey_phase(*args):
+        launched.append(args)
+        return object()
     items = _sig_items(3)
     pks, msg, sig = items[position]
     if kind == "pubkey":
         items[position] = (pks[:-1] + [INF_PK], msg, sig)
+        pubkey_phase = no_device
     else:
         items[position] = (pks, msg, b"\x00" * 96)
-    monkeypatch.setattr(bls_jax, "rlc_combined_check", no_device)
+    monkeypatch.setattr(bls_jax, "rlc_pubkey_phase", pubkey_phase)
+    for mod, name in ((bls_jax, "rlc_signature_phase"),
+                      (bls_jax, "_j_g2_scale_sum"),
+                      (PR, "staged_product_pairing_check")):
+        monkeypatch.setattr(mod, name, no_device)
+    used, dropped = (_EARLY.value(outcome="used"),
+                     _EARLY.value(outcome="discarded"))
     assert bls_rlc._check_jax(items, (), [1, 2, 3]) is None
+    assert len(launched) == (kind == "signature")
+    assert _EARLY.value(outcome="used") == used
+    assert _EARLY.value(outcome="discarded") - dropped == len(launched)
+
+
+_NUMPY_ORDER_CHECK = r"""
+import sys
+sys.path.insert(0, %(repo)r)
+from consensus_specs_tpu.ops.jax_bls.backend import NUMPY_KERNELS
+assert NUMPY_KERNELS
+from consensus_specs_tpu.obs import registry
+from consensus_specs_tpu.ops import bls_jax, bls_rlc
+from consensus_specs_tpu.utils import bls
+
+bls.use_py()
+msg = b"ord" * 8
+pks = [bls.SkToPk(i) for i in (1, 2, 3)]
+valid = [(pks, msg, bls.Aggregate([bls.Sign(i, msg) for i in (1, 2, 3)]))]
+valid += [([bls.SkToPk(i)], bytes([i]) * 32, bls.Sign(i, bytes([i]) * 32))
+          for i in (5, 6)]
+# a well-formed signature over another message
+wrong = valid[:2] + [(valid[2][0], valid[2][1], bls.Sign(6, msg))]
+
+calls = []
+tree_sum, decompress = bls_jax._j_tree_sum, bls_jax._decompress_g2
+def traced_tree_sum(*a):
+    calls.append("tree_sum")
+    return tree_sum(*a)
+def traced_decompress(*a):
+    calls.append("decompress")
+    return decompress(*a)
+bls_jax._j_tree_sum = traced_tree_sum
+bls_jax._decompress_g2 = traced_decompress
+early = registry.counter("bls.rlc.early_dispatch")
+for items in (valid, wrong):
+    scalars = bls_rlc.derive_scalars(items)
+    calls.clear()
+    used = early.value(outcome="used")
+    got = bls_rlc._check_jax(items, (), scalars)
+    print("VERDICT", got, bls_rlc._check_py(items, (), scalars))
+    print("FIRST", calls[0], calls.count("decompress"))
+    print("USED", early.value(outcome="used") - used)
+"""
+
+
+def test_check_jax_launches_pubkey_side_before_decompression():
+    """``_check_jax`` issues the pubkey tree sum before it decodes the
+    first signature, counts ``bls.rlc.early_dispatch{outcome=used}``
+    once a flush, and answers as ``_check_py`` on a valid batch and on
+    one with a wrong but well-formed signature (numpy kernels)."""
+    import subprocess
+    import sys
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, CS_TPU_NUMPY_KERNELS="1")
+    proc = subprocess.run(
+        [sys.executable, "-c", _NUMPY_ORDER_CHECK % {"repo": repo}],
+        env=env, capture_output=True, timeout=600, cwd=repo)
+    assert proc.returncode == 0, proc.stderr.decode()[-2000:]
+    lines = proc.stdout.decode().splitlines()
+    assert [ln for ln in lines if ln.startswith("VERDICT")] == [
+        "VERDICT True True", "VERDICT False False"], lines
+    assert [ln for ln in lines if ln.startswith("FIRST")] == [
+        "FIRST tree_sum 3"] * 2, lines
+    assert [ln for ln in lines if ln.startswith("USED")] == [
+        "USED 1"] * 2, lines
 
 
 # ---------------------------------------------------------------------------
